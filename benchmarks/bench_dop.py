@@ -39,10 +39,11 @@ def tile_utilization(cfg, tile_m: int) -> dict:
     dop_equiv = width_fill * ch_fill * lanes * sublanes
     macs_per_sym = cfg.mac_per_symbol()
     flops_per_sym = 2 * macs_per_sym
-    eff_flops = rl.PEAK_FLOPS * width_fill * ch_fill
+    v5e = rl.peaks(rl.V5E)
+    eff_flops = v5e["bf16_flops"] * width_fill * ch_fill
     t_comp = flops_per_sym / eff_flops
     bytes_per_sym = (cfg.n_os + 1) * 2.0
-    t_mem = bytes_per_sym / rl.HBM_BW
+    t_mem = bytes_per_sym / v5e["hbm_bytes_per_s"]
     rate = 1.0 / max(t_comp, t_mem)
     return {"tile_m": tile_m, "lane_fill": width_fill, "chan_fill": ch_fill,
             "dop_equivalent_macs": dop_equiv,

@@ -36,14 +36,15 @@ def tpu_projection(cfg) -> dict:
     # bytes/sym: stream in (N_os samples bf16) + out (1 sym bf16); weights
     # stay in VMEM
     bytes_per_sym = (cfg.n_os + 1) * 2.0
-    t_comp = flops_per_sym / rl.PEAK_FLOPS
-    t_mem = bytes_per_sym / rl.HBM_BW
+    v5e = rl.peaks(rl.V5E)
+    t_comp = flops_per_sym / v5e["bf16_flops"]
+    t_mem = bytes_per_sym / v5e["hbm_bytes_per_s"]
     sym_rate = 1.0 / max(t_comp, t_mem)
     return {
         "sym_rate_gsyms": sym_rate / 1e9,
         "throughput_gbps_pam2": sym_rate / 1e9,
         "bound": "compute" if t_comp > t_mem else "memory",
-        "mfu_at_bound": flops_per_sym / (sym_rate ** -1) / rl.PEAK_FLOPS,
+        "mfu_at_bound": flops_per_sym * sym_rate / v5e["bf16_flops"],
     }
 
 

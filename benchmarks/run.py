@@ -74,6 +74,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (bench_adapt, bench_dop, bench_dse, bench_engine,
                bench_fault, bench_fleet, bench_link, bench_net,
                bench_obs, bench_platform, bench_proakis, bench_quant,
@@ -383,6 +385,7 @@ def check(tol: float | None = None) -> int:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale sweeps (hours)")

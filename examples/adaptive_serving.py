@@ -30,6 +30,7 @@ import numpy as np
 from repro.adapt import (AdaptPolicy, FineTuneConfig, OnlineAdapter,
                          PromotionPolicy, engine_ber, hard_decide)
 from repro.channels.drift import DriftingProakis, DriftSchedule
+from repro.compile_cache import enable_compile_cache
 from repro.core import equalizer as eq
 from repro.core.train_eq import EqTrainConfig, train_equalizer
 from repro.serve import (AsyncServeRuntime, BatchPolicy, ServeRuntime,
@@ -51,6 +52,7 @@ def burst_ber(soft, pilots):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--bursts", type=int, default=26)
     ap.add_argument("--syms-per-burst", type=int, default=2048)

@@ -8,6 +8,7 @@ path (BN folded, fused Pallas kernel in interpret mode).
 import jax
 
 from repro.channels import imdd
+from repro.compile_cache import enable_compile_cache
 from repro.core.equalizer import CNNEqConfig
 from repro.core.fir import FIRConfig
 from repro.core.train_eq import EqTrainConfig, train_equalizer
@@ -16,6 +17,7 @@ from repro.kernels.cnn_eq import ops as cnn_ops
 
 
 def main():
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     fn = channel_fn("imdd", imdd.IMDDConfig())
     tcfg = EqTrainConfig(steps=600, batch=8, seq_syms=256, lr=3e-3,

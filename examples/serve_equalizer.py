@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.channels import imdd, proakis
+from repro.compile_cache import enable_compile_cache
 from repro.configs import equalizer_ht as HT
 from repro.configs import equalizer_lp as LP
 from repro.core import equalizer as eq
@@ -62,6 +63,7 @@ def make_tenant(op: str, idx: int, n_syms: int):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants-per-op", type=int, default=3)
     ap.add_argument("--n-syms", type=int, default=2048)

@@ -6,10 +6,12 @@ the same code path serves the full configs on a pod (launch/serve.py).
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch import serve
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     args = ap.parse_args()

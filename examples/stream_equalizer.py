@@ -3,27 +3,30 @@
     OGM (overlap) → SSM tree (split) → N_i × CNN → MSM (merge) → ORM
 
 run two ways: (1) the pure-JAX reference (any machine), and (2) the
-TPU-native halo-exchange shard_map over N_i fake CPU devices (this script
-re-executes itself with XLA_FLAGS to get the device pool).
+TPU-native halo-exchange shard_map with one CNN instance per device, over
+every device the process sees (N_i = device count).
 
-    PYTHONPATH=src python examples/stream_equalizer.py [--instances 8]
+    PYTHONPATH=src python examples/stream_equalizer.py
+
+On a CPU host, give JAX several virtual devices for the mesh:
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \\
+        PYTHONPATH=src python examples/stream_equalizer.py
 """
-import argparse
-import os
-import subprocess
-import sys
+import jax
+import jax.numpy as jnp
+
+from repro.channels import imdd
+from repro.compile_cache import enable_compile_cache
+from repro.core import equalizer as eq
+from repro.core import seqlen_opt, stream_partition as sp
+from repro.core import timing_model as tm
+from repro.core.engine import EqualizerEngine
+from repro.parallel import halo
 
 
-def main_inner(n_inst: int):
-    import jax
-    import jax.numpy as jnp
-    from repro.channels import imdd
-    from repro.core import equalizer as eq
-    from repro.core import seqlen_opt, stream_partition as sp
-    from repro.core import timing_model as tm
-    from repro.core.engine import EqualizerEngine
-    from repro.parallel import halo
-
+def main() -> None:
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     cfg = eq.CNNEqConfig()
     params = eq.init(key, cfg)
@@ -33,6 +36,7 @@ def main_inner(n_inst: int):
     engine = EqualizerEngine.from_params(params, eq.init_bn_state(cfg), cfg,
                                          backend="auto", tile_m="auto")
 
+    n_inst = len(jax.devices())
     n_syms = 1024 * n_inst
     rx, _ = imdd.simulate(key, imdd.IMDDConfig(), n_syms)
 
@@ -43,7 +47,7 @@ def main_inner(n_inst: int):
     o = sp.overlap_symbols(cfg)
     err_ref = float(jnp.max(jnp.abs(y_ref[o:-o] - y_single[o:-o])))
     err_halo = float(jnp.max(jnp.abs(y_halo[o:-o] - y_single[o:-o])))
-    print(f"{n_inst} instances over {len(jax.devices())} devices "
+    print(f"{n_inst} instances over {n_inst} devices "
           f"(engine: {engine.describe()}):")
     print(f"  split-tree reference vs single instance (interior): "
           f"max err {err_ref:.2e}")
@@ -58,16 +62,4 @@ def main_inner(n_inst: int):
 
 
 if __name__ == "__main__":
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--instances", type=int, default=8)
-    ap.add_argument("--inner", action="store_true")
-    args = ap.parse_args()
-    if args.inner:
-        main_inner(args.instances)
-    else:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                            f"{args.instances}")
-        sys.exit(subprocess.run(
-            [sys.executable, __file__, "--inner",
-             "--instances", str(args.instances)], env=env).returncode)
+    main()

@@ -12,6 +12,7 @@ import jax
 
 from repro.channels import imdd
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.core import dse, qat as qat_lib
 from repro.core.equalizer import CNNEqConfig
 from repro.core.train_eq import EqTrainConfig, train_equalizer
@@ -19,6 +20,7 @@ from repro.data.equalizer_data import channel_fn
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=1200)
     ap.add_argument("--qlf", type=float, default=5e-4)

@@ -2,8 +2,8 @@
 
 The paper's DOP knob (how many MACs the FPGA instantiates per layer) maps on
 TPU to the fused kernel's sequence-tile width `tile_m`: it sets how much of
-the MXU's 128-lane axis each tap-matmul fills and how well the tile DMAs
-overlap compute. The best value depends on the topology (receptive field →
+the vector unit's 128-lane axis each tap's products fill and how well the
+tile DMAs overlap compute. The best value depends on the topology (receptive field →
 halo overhead per tile) and on the backend (int8 tiles fit 4× more VMEM),
 so DOP-style operating points (`equalizer_ht`, `equalizer_lp`) each get
 their own sweep.
@@ -33,15 +33,17 @@ _memory_cache: Dict[Tuple, int] = {}
 
 
 def cache_key(cfg: CNNEqConfig, backend: str) -> Tuple:
-    # platform is part of the key: an interpret-mode sweep on a CPU host
-    # must not pin the tile choice for real TPU silicon (and vice versa)
+    # the device kind is part of the key: an interpret-mode sweep on a CPU
+    # host must not pin the tile choice for TPU silicon, nor a v5e sweep
+    # the tile of another chip
+    kind = jax.devices()[0].device_kind.replace(" ", "_")
     return (cfg.layers, cfg.kernel, cfg.channels, cfg.v_parallel, cfg.n_os,
-            backend, jax.default_backend())
+            backend, kind)
 
 
 def _key_str(key: Tuple) -> str:
-    l, k, c, vp, nos, backend, platform = key[:7]
-    s = f"L{l}_K{k}_C{c}_Vp{vp}_Nos{nos}__{backend}__{platform}"
+    l, k, c, vp, nos, backend, kind = key[:7]
+    s = f"L{l}_K{k}_C{c}_Vp{vp}_Nos{nos}__{backend}__{kind}"
     if len(key) > 7:                   # batched-serving sweep (probe_batch>1)
         s += f"__B{key[7]}"
     if len(key) > 8:                   # serve-aware sweep: live-traffic width

@@ -10,11 +10,11 @@ lambdas. The engine owns:
   * backend selection:
       - "ref"        pure-jnp stream-semantics oracle (kernels.cnn_eq.ref),
       - "fused_fp32" the fused Pallas kernel — same math as "ref",
-      - "fused_bf16" the fused Pallas kernel with bf16 tap dots and fp32
-        accumulation — the native datapath for QAT formats in the 9–16-bit
-        range (qat.deployment_dtype == "bfloat16"),
+      - "fused_bf16" the fused Pallas kernel with bf16 operands and fp32
+        products and sums — the native datapath for QAT formats in the
+        9–16-bit range (qat.deployment_dtype == "bfloat16"),
       - "fused_int8" the quantized fused Pallas kernel: int8 weights at
-        QAT's learned per-layer scales, int8×int8 MXU dots with int32
+        QAT's learned per-layer scales, int8-grid products with int32
         accumulation and fused requantization between layers,
       - "auto"       fused_int8 when trained QAT formats deploy to int8
         AND the BN-folded weights still fit the learned grid; else
@@ -138,7 +138,7 @@ class EqualizerEngine:
         per_channel=True refines the learned per-layer weight formats to
         per-output-channel scales (`qat.per_channel_formats`) before the
         backend decision: same learned total width, finer grids on channels
-        with small folded weights — no extra MXU cost (the requant is
+        with small folded weights — no extra arithmetic (the requant is
         already per-row). This is a DEPLOYMENT refinement; the formats are
         derived deterministically from the folded weights, so engine
         rebuilds (e.g. after serve-pool eviction) reproduce them exactly.

@@ -18,7 +18,7 @@ Three-phase schedule (paper Fig. 5/6):
   widths frozen to the next-highest integer.
 
 TPU note (DESIGN.md §2): widths are *learned* exactly as on the FPGA; at
-deployment the learned (i, f) map to the nearest MXU-native dtype (int8 /
+deployment the learned (i, f) map to the nearest native dtype (int8 /
 bf16) — `deployment_dtype()` below.
 """
 from __future__ import annotations
@@ -167,8 +167,8 @@ def per_channel_formats(weights, formats):
     TOTAL weight width (w_int + w_frac — the trained accuracy/width
     trade-off) but redistributes it per output channel: a channel whose
     folded weights are small narrows its integer width and reclaims the
-    bits as fraction width (a finer grid). This costs nothing on the MXU —
-    the int8 dot is unchanged; only the (already per-row) requantization
+    bits as fraction width (a finer grid). This costs no extra arithmetic —
+    the integer products are unchanged; only the (already per-row) requantization
     scale becomes a per-channel vector (`repro.kernels.cnn_eq`).
 
     weights: BN-folded ((w, b), …) — per-channel ranges come from the
@@ -232,8 +232,8 @@ def _format_dtype(total_bits: int) -> str:
 def plan_backend(plan: Dict[str, Any]) -> str:
     """Map a deployment plan to the engine backend that serves it natively.
 
-    all layers int8        → "fused_int8"   (int8 MXU dots, int32 accum)
-    all layers ≤ 16 bits   → "fused_bf16"   (bf16 MXU dots, fp32 accum —
+    all layers int8        → "fused_int8"   (int8 grid, int32 sums)
+    all layers ≤ 16 bits   → "fused_bf16"   (bf16 operands, fp32 sums —
                               bf16's exponent covers any learned int width,
                               its 8-bit mantissa the 9–16-bit fractions)
     anything wider         → "fused_fp32"

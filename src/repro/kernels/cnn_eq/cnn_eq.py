@@ -9,20 +9,28 @@ activations never leave VMEM:
                  │ conv1 (stride V_p) + ReLU        ┐ all in VMEM /
                  │ conv2 … conv_{L-1} + ReLU        │ vector registers —
                  │ conv_L (stride N_os)             ┘ zero HBM round-trips
-  HBM ◀──DMA── VMEM output tile (tile_m · V_p symbols)
+  HBM ◀──DMA── VMEM output tile (V_p × tile_m symbols, channel-major)
 
 Grid = (batch, sequence tiles): Mosaic overlaps the tile DMAs with compute,
 which is exactly the paper's "each layer starts as soon as first inputs
 arrive" streaming property, realized at tile granularity.
 
-Each grid step takes its overlapping input window (half a receptive field of
-halo per side, `receptive_halo`) with an in-kernel `pl.ds` dynamic slice of
-the padded stream; the kernel computes VALID convolutions and the wrapper
-pre-pads the stream so the result equals the SAME_LOWER-padded reference
-(`ref.cnn_eq`) — including at stream edges. The fp32 kernel reuses
-`ref.conv_valid_taps` for its layer math (same dots, same accumulation
-order), matching the oracle to ~2 ULP; the int8 kernel matches its
-fake-quant oracle exactly (integer arithmetic has no rounding freedom).
+Layout. The wrapper hands each grid step its own input window (half a
+receptive field of halo per side, `receptive_halo`) in POLYPHASE form: the
+padded stream split into T = V_p·N_os phases, so that every tap of every
+layer reads one phase at a static unit-stride offset (`_phase_plan`) — the
+TPU compiler refuses strided lane slices, and interpret mode would not have
+said so. The kernel computes VALID convolutions and the wrapper pre-pads the
+stream so the result equals the SAME_LOWER-padded reference (`ref.cnn_eq`) —
+including at stream edges. The output leaves the kernel channel-major and is
+interleaved into symbol order by XLA.
+
+Arithmetic. With C ≤ 8 channels a conv tap is far too small for the matrix
+unit, so each tap is a handful of elementwise products on the vector unit,
+added in the oracle's order (`ref.conv_valid_taps`). That fixes the
+accumulation order on every backend: the fp32 kernel matches its oracle to
+~2 ULP, the bf16 kernel (exact bf16 products) bitwise, and the int8 kernel
+its fake-quant oracle exactly (integer arithmetic has no rounding freedom).
 
 INT8 datapath (`cnn_eq_fused_int8`) — the deployment path when QAT's learned
 per-layer fixed-point formats fit int8 (qat.deployment_dtype == "int8").
@@ -31,17 +39,17 @@ are requantized INSIDE the kernel between layers, so the whole quantized
 stack stays fused in VMEM:
 
       x (fp32 tile, VMEM)
-        │ requant:  q = clip(round(x · 2^af₁))        → int8
-        │ conv1:    int8 × int8 MXU dots              → int32 accum
+        │ requant:  q = clip(round(x · 2^af₁))        → int8 grid
+        │ conv1:    int8-grid products, int32 sums
         │ rescale:  acc · 2^-(wf₁+af₁) + b₁ (fp32)    → fp32
-        │ ReLU ──▶ requant 2^af₂ → int8 ──▶ conv2 ──▶ … conv_L
+        │ ReLU ──▶ requant 2^af₂ ──▶ conv2 ──▶ … conv_L
         ▼
       y (fp32 symbols, VMEM)
 
-The integer dot is exact (|w|·|a| ≤ 127², ΣC_in·K terms ≪ 2³¹) and the
+The integer sums are exact (|w|·|a| ≤ 127·128, ΣC_in·K terms ≪ 2³¹) and the
 rescale multiplies by a power of two, so the kernel reproduces the QAT
-fake-quant reference (`ref.cnn_eq_quant`) to within one accumulation LSB —
-quantization error comes ONLY from the learned formats, never the kernel.
+fake-quant reference (`ref.cnn_eq_quant`) exactly — quantization error comes
+ONLY from the learned formats, never the kernel.
 """
 from __future__ import annotations
 
@@ -53,7 +61,7 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import numpy as np
 
-from .ref import conv_valid_taps, conv_valid_taps_bf16, receptive_halo
+from .ref import receptive_halo
 
 
 def _wformat_cols(wi, wf):
@@ -69,57 +77,80 @@ def _wformat_cols(wi, wf):
             np.asarray(wf, np.float32).reshape(-1, 1))
 
 
-def _layer_spans(tile_m: int, kernels: Sequence[int],
-                 strides: Sequence[int]) -> list[int]:
-    """Positions needed at each level to produce tile_m final positions."""
-    spans = [tile_m]
-    for k, s in zip(reversed(kernels), reversed(strides)):
-        spans.append((spans[-1] - 1) * s + k)
-    return list(reversed(spans))  # spans[0] = input samples per tile
+def _phase_plan(kernels: Sequence[int], strides: Sequence[int],
+                tile_m: int) -> Tuple[list[int], list[int]]:
+    """Polyphase geometry of one tile.
 
-
-def _layer_wb(w_ref, b_ref):
-    """Read one layer's (w, b) block, squeezing the per-row tenant dim.
-
-    Weights arrive either SHARED across the batch (w: (C_out, C_in, K),
-    b: (C_out,) — every grid row sees the same block) or STACKED per row
-    (w: (1, C_out, C_in, K), b: (1, C_out) — the BlockSpec selected THIS
-    row's tenant weights). The kernel math is identical after the squeeze;
-    this is what lets one fused launch serve many tenants (repro.serve).
+    Level i (the input of layer i; level L = the output) keeps its positions
+    split into phases[i] = T / (s_0···s_{i-1}) interleaved phases (T = ∏
+    strides): phase r holds positions r, r + phases[i], …. With the input at
+    T phases and the output at one, every tap of every layer reads ONE phase
+    at a static, unit-stride column offset — no strided lane slices (which
+    Mosaic refuses). cols[i] is the number of columns per phase at level i
+    that tile_m final positions need.
     """
-    w = w_ref[...]
-    b = b_ref[...]
-    if w.ndim == 4:
-        w = w[0]
-    if b.ndim == 2:
-        b = b[0]
-    return w, b
-
-
-def _cnn_eq_kernel(x_ref, *refs, tile_m: int, in_tile: int, kernels, strides,
-                   v_parallel: int, conv_fn=conv_valid_taps):
-    """Float kernel body; conv_fn picks the datapath — `conv_valid_taps`
-    (fp32) or `conv_valid_taps_bf16` (bf16 dots, fp32 accum) — mirroring
-    the conv_fn parameterization of the oracle (`ref._stack_valid`)."""
-    n_layers = len(kernels)
-    w_refs = refs[:-1][0::2]
-    b_refs = refs[:-1][1::2]
-    o_ref = refs[-1]
-    spans = _layer_spans(tile_m, kernels, strides)
-    total_stride = 1
+    phases = [int(np.prod(strides))]
     for s in strides:
-        total_stride *= s
+        phases.append(phases[-1] // s)
+    cols = [tile_m]
+    for i in reversed(range(len(strides))):
+        reach = (phases[i + 1] - 1) * strides[i] + kernels[i] - 1
+        cols.append(cols[-1] + reach // phases[i])
+    return phases, cols[::-1]
 
-    start = pl.program_id(1) * (tile_m * total_stride)
-    h = x_ref[:, pl.ds(start, in_tile)].astype(jnp.float32)  # (1, in_tile)
+
+def _poly_conv(h, w_ref, stride: int, n_phases_out: int, n_cols: int):
+    """One VALID conv layer on polyphase activations, on the vector unit.
+
+    h: list of (C_in, cols) phase arrays and w_ref: (C_out, K·C_in) weight
+    columns (column k·C_in + c is tap k, input channel c), both already in
+    the accumulation dtype. Output position p = j·R' + r reads input
+    p·stride + k = j·R + (r·stride + k), i.e. phase (r·stride + k) mod R at
+    column j + (r·stride + k) div R. Each output element is the oracle's sum
+    (`ref.conv_valid_taps`): taps k = 0 … K-1, each the products over input
+    channels c = 0 … C_in-1 added in order. Elementwise products fix that
+    order on every backend; a matrix unit's dot would not.
+    """
+    r_in, c_in = len(h), h[0].shape[0]
+    w = w_ref[...]
+    out = []
+    for r in range(n_phases_out):
+        acc = None
+        for k in range(w.shape[1] // c_in):
+            t = r * stride + k
+            x = h[t % r_in][:, t // r_in:t // r_in + n_cols]
+            term = None
+            for c in range(c_in):
+                j = k * c_in + c
+                prod = w[:, j:j + 1] * x[c:c + 1]
+                term = prod if term is None else term + prod
+            acc = term if acc is None else acc + term
+        out.append(acc)
+    return out
+
+
+def _input_phases(x_ref):
+    """This tile's input as T phase rows of shape (1, in_cols)."""
+    x = x_ref[...].astype(jnp.float32)                       # (T, in_cols)
+    return [x[p:p + 1] for p in range(x.shape[0])]
+
+
+def _cnn_eq_kernel(x_ref, *refs, tile_m: int, kernels, strides, operand):
+    """Float kernel body. `operand` is the datapath: float32, or bfloat16 —
+    activations and weights rounded to bf16, products and sums in fp32
+    (a bf16·bf16 product is exact in fp32)."""
+    n_layers = len(kernels)
+    w_refs, b_refs, o_ref = refs[:-1][0::2], refs[:-1][1::2], refs[-1]
+    phases, cols = _phase_plan(kernels, strides, tile_m)
+    h = _input_phases(x_ref)
     for i in range(n_layers):
-        w, b = _layer_wb(w_refs[i], b_refs[i])
-        h = conv_fn(h, w, b, strides[i], spans[i + 1])
+        h = [p.astype(operand).astype(jnp.float32) for p in h]
+        b = b_refs[i][...]                                   # (C_out, 1)
+        h = [acc + b for acc in _poly_conv(h, w_refs[i], strides[i],
+                                           phases[i + 1], cols[i + 1])]
         if i < n_layers - 1:
-            h = jax.nn.relu(h)
-    # (V_p, tile_m) → interleave channels: symbol s = m·V_p + c
-    y = jnp.swapaxes(h, 0, 1).reshape(1, tile_m * v_parallel)
-    o_ref[...] = y.astype(o_ref.dtype)
+            h = [jax.nn.relu(p) for p in h]
+    o_ref[...] = h[0].astype(o_ref.dtype)                    # (V_p, tile_m)
 
 
 def requant_int8(h: jnp.ndarray, a_int: int, a_frac: int) -> jnp.ndarray:
@@ -143,47 +174,43 @@ def dequant_int8(q: jnp.ndarray, a_frac: int) -> jnp.ndarray:
 _requant = requant_int8          # kernel-internal alias
 
 
-def _cnn_eq_kernel_int8(x_ref, *refs, tile_m: int, in_tile: int, kernels,
-                        strides, v_parallel: int, formats):
+def _cnn_eq_kernel_int8(x_ref, *refs, tile_m: int, kernels, strides,
+                        formats):
     n_layers = len(kernels)
-    body = refs[:-1]             # per layer: (w int8, b fp32, rescale fp32)
-    w_refs = body[0::3]          # int8 weights, pre-scaled by 2^w_frac
+    body = refs[:-1]             # per layer: (w int32, b fp32, rescale fp32)
+    w_refs = body[0::3]          # int8 grid weights (x 2^w_frac) as int32
     b_refs = body[1::3]          # fp32 biases (full-width accumulators)
-    s_refs = body[2::3]          # (C_out,) exact power-of-two rescale —
-    #   2^-(w_frac + a_frac) per OUTPUT CHANNEL. A uniform vector for the
+    s_refs = body[2::3]          # (C_out, 1) exact power-of-two rescale —
+    #   2^-(w_frac + a_frac) per OUTPUT CHANNEL. A uniform column for the
     #   paper's one-scale-per-layer scheme; genuinely per-channel for
-    #   `qat.per_channel_formats` deployments. Either way the int8 dot
-    #   below is identical — per-channel scales cost no MXU work, only
-    #   this rescale column (Pallas cannot capture array constants, hence
-    #   an operand rather than a baked-in value).
+    #   `qat.per_channel_formats` deployments. Either way the integer
+    #   products below are identical — per-channel scales cost only this
+    #   rescale column (Pallas cannot capture array constants, hence an
+    #   operand rather than a baked-in value).
     o_ref = refs[-1]
-    spans = _layer_spans(tile_m, kernels, strides)
-    total_stride = 1
-    for s in strides:
-        total_stride *= s
-
-    start = pl.program_id(1) * (tile_m * total_stride)
-    h = x_ref[:, pl.ds(start, in_tile)].astype(jnp.float32)
+    phases, cols = _phase_plan(kernels, strides, tile_m)
+    h = _input_phases(x_ref)
     for i in range(n_layers):
         _, _, ai, af = formats[i]
-        hq = _requant(h, ai, af)                     # fused requantization
-        w, b = _layer_wb(w_refs[i], b_refs[i])
-        n_out = spans[i + 1]
-        k = w.shape[-1]
-        acc = jnp.zeros((w.shape[0], n_out), jnp.int32)
-        for kk in range(k):
-            xk = jax.lax.slice(
-                hq, (0, kk), (hq.shape[0], kk + (n_out - 1) * strides[i] + 1),
-                (1, strides[i]))
-            acc = acc + jax.lax.dot(w[:, :, kk], xk,
-                                    preferred_element_type=jnp.int32)
+        # fused requantization; int32 products and sums are exact
+        hq = [_requant(p, ai, af).astype(jnp.int32) for p in h]
+        scale = s_refs[i][...]
+        b = b_refs[i][...]
         # exact power-of-two rescale back to real units, then fp32 bias
-        h = acc.astype(jnp.float32) * s_refs[i][...][:, None] \
-            + b.astype(jnp.float32)[:, None]
+        h = [acc.astype(jnp.float32) * scale + b
+             for acc in _poly_conv(hq, w_refs[i], strides[i], phases[i + 1],
+                                   cols[i + 1])]
         if i < n_layers - 1:
-            h = jax.nn.relu(h)
-    y = jnp.swapaxes(h, 0, 1).reshape(1, tile_m * v_parallel)
-    o_ref[...] = y.astype(o_ref.dtype)
+            h = [jax.nn.relu(p) for p in h]
+    o_ref[...] = h[0].astype(o_ref.dtype)
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """`interpret=None` means interpret mode exactly when JAX's default
+    backend is the CPU; an explicit bool is taken as given."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
 
 
 def _fused_call(kernel_body, x, weights, strides, tile_m, interpret,
@@ -194,9 +221,21 @@ def _fused_call(kernel_body, x, weights, strides, tile_m, interpret,
     row — or STACKED per row — w: (B, C_out, C_in, K), b: (B, C_out), batch
     row i computed with weight set i. The stacked form is the multi-tenant
     serving path: one launch, per-tenant weights selected by the BlockSpec.
+
+    Layouts the TPU compiler accepts, all built here by XLA:
+      * input: per-tile windows of the padded stream in T = ∏strides
+        phases, (B, n_tiles, T, in_cols), so every in-kernel tap is a
+        static unit-stride slice and no load needs an aligned offset;
+      * weights: one (C_out, K·C_in) matrix per layer (column k·C_in + c
+        is tap k, input channel c), biases and rescales as (C_out, 1)
+        columns; the batch dim of stacked operands is squeezed;
+      * output: channel-major (B, n_tiles, V_p, tile_m) tiles whose last two
+        block dims equal the array's, so every tile_m ≥ 1 is a legal block;
+        the symbol interleave s = m·V_p + c happens after the kernel.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
+    if tile_m < 1:
+        raise ValueError(f"tile_m must be a positive int, got {tile_m}")
     batch, width = x.shape
     stacked = weights[0][0].ndim == 4
     if stacked and int(weights[0][0].shape[0]) != batch:
@@ -204,62 +243,73 @@ def _fused_call(kernel_body, x, weights, strides, tile_m, interpret,
             f"stacked weights carry {int(weights[0][0].shape[0])} rows but "
             f"x has batch {batch}")
     kernels = tuple(int(item[0].shape[-1]) for item in weights)
-    v_parallel = int(weights[-1][0].shape[1 if stacked else 0])
-    total_stride = 1
-    for s in strides:
-        total_stride *= s
+    v_parallel = int(weights[-1][0].shape[-3])
+    total_stride = int(np.prod(strides))
     n_pos = width // total_stride                  # final-layer positions
     n_syms = n_pos * v_parallel
 
     # Always tile at the REQUESTED tile_m — even for a stream shorter than
-    # one tile. Shrinking the tile to n_pos would change the conv dot shapes
-    # (and with them the fp32 accumulation splits) relative to a streaming
-    # launch that buckets at full tile_m, costing 1-2 ULP in end-padding
-    # window positions and breaking chunked==offline bitwise equality
-    # (contract #4). Short streams just compute a few extra padded positions
-    # that the final n_syms slice drops.
-    tile_m = max(1, tile_m)
+    # one tile: the serving chunker buckets launches at whole tiles, and a
+    # launch must compute every position with the same program shapes as
+    # the offline call for chunked==offline bitwise equality (contract #4).
+    # Short streams just compute a few extra padded positions that the
+    # final n_syms slice drops.
     n_tiles = pl.cdiv(n_pos, tile_m)
     halo = receptive_halo(kernels, strides)
-    in_tile = _layer_spans(tile_m, kernels, strides)[0]
+    in_cols = _phase_plan(kernels, strides, tile_m)[1][0]
 
-    # pad: halo on the left; halo + tile rounding on the right
-    needed = (n_tiles - 1) * tile_m * total_stride + in_tile
-    xp = jnp.pad(x, ((0, 0), (halo, max(0, needed - width - halo))))
+    # Tile-local input windows in phase layout, (B, n_tiles, T, in_cols):
+    # window `it` is phase columns [it·tile_m, it·tile_m + in_cols) of the
+    # padded stream, built from the `reach` following tile blocks (slices
+    # and a concat, no gather). Halo on the left, zeros on the right; any
+    # samples past the last window feed no kept position.
+    reach = pl.cdiv(in_cols - tile_m, tile_m)
+    padded = (n_tiles + reach) * tile_m * total_stride
+    xp = jnp.pad(x, ((0, 0), (halo, max(0, padded - width - halo))))
+    blocks = xp[:, :padded].reshape(batch, n_tiles + reach, tile_m,
+                                    total_stride)
+    xp = jnp.concatenate([blocks[:, j:j + n_tiles] for j in range(reach + 1)],
+                         axis=2)[:, :, :in_cols]
+    xp = jnp.swapaxes(xp, 2, 3)                    # (B, n_tiles, T, in_cols)
 
-    flat: list[jnp.ndarray] = []
-    in_specs = [pl.BlockSpec((1, xp.shape[1]), lambda ib, it: (ib, 0))]
+    def full(shape):
+        return pl.BlockSpec(shape, lambda ib, it: (0,) * len(shape))
+
+    def per_row(shape):
+        return pl.BlockSpec((None,) + shape,
+                            lambda ib, it: (ib,) + (0,) * len(shape))
+
+    flat: list[jnp.ndarray] = [xp]
+    in_specs = [pl.BlockSpec((None, None) + xp.shape[2:],
+                             lambda ib, it: (ib, it, 0, 0))]
     for item in weights:
-        w, b = item[0], item[1]
+        w = jnp.swapaxes(item[0], -1, -2)          # (…, C_out, K, C_in)
+        w = w.reshape(w.shape[:-2] + (-1,))        # (…, C_out, K·C_in)
+        b = item[1].astype(jnp.float32)[..., None]  # (…, C_out, 1)
         flat += [w, b]
-        if stacked:
-            in_specs += [pl.BlockSpec((1,) + w.shape[1:],
-                                      lambda ib, it: (ib, 0, 0, 0)),
-                         pl.BlockSpec((1, b.shape[1]),
-                                      lambda ib, it: (ib, 0))]
-        else:
-            in_specs += [pl.BlockSpec(w.shape, lambda ib, it: (0, 0, 0)),
-                         pl.BlockSpec(b.shape, lambda ib, it: (0,))]
+        spec = per_row if stacked else full
+        in_specs += [spec(w.shape[-2:]), spec(b.shape[-2:])]
         # trailing per-layer operands (e.g. the int8 rescale column) are
         # SHARED across batch rows even in stacked launches: they derive
         # from the static formats, which every engine in a group shares
         # (formats are part of group_key)
         for extra in item[2:]:
-            flat.append(extra)
-            in_specs.append(pl.BlockSpec(extra.shape, lambda ib, it: (0,)))
+            flat.append(extra[:, None])
+            in_specs.append(full(flat[-1].shape))
 
     out = pl.pallas_call(
-        functools.partial(kernel_body, tile_m=tile_m, in_tile=in_tile,
-                          kernels=kernels, strides=strides,
-                          v_parallel=v_parallel, **kernel_kwargs),
+        functools.partial(kernel_body, tile_m=tile_m, kernels=kernels,
+                          strides=strides, **kernel_kwargs),
         grid=(batch, n_tiles),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tile_m * v_parallel),
-                               lambda ib, it: (ib, it)),
+        out_specs=pl.BlockSpec((None, None, v_parallel, tile_m),
+                               lambda ib, it: (ib, it, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (batch, n_tiles * tile_m * v_parallel), x.dtype),
+            (batch, n_tiles, v_parallel, tile_m), x.dtype),
         interpret=interpret,
-    )(xp, *flat)
+    )(*flat)
+    # (B, n_tiles, V_p, tile_m) → interleave channels: symbol s = m·V_p + c
+    out = jnp.swapaxes(out, 2, 3).reshape(batch, n_tiles * tile_m * v_parallel)
     return out[:, :n_syms]
 
 
@@ -276,7 +326,8 @@ def cnn_eq_fused(x: jnp.ndarray,
     — see `_fused_call`. strides: (V_p, 1, …, N_os).
     Output length = W // (V_p·N_os) · V_p.
     """
-    return _fused_call(_cnn_eq_kernel, x, weights, strides, tile_m, interpret)
+    return _fused_call(_cnn_eq_kernel, x, weights, strides, tile_m, interpret,
+                       operand=jnp.float32)
 
 
 def cast_weights_bf16(
@@ -294,17 +345,19 @@ def cnn_eq_fused_bf16(x: jnp.ndarray,
                       bweights: Tuple[Tuple[jnp.ndarray, jnp.ndarray], ...],
                       strides: Tuple[int, ...], tile_m: int = 64,
                       interpret: bool | None = None) -> jnp.ndarray:
-    """Fused bf16 equalizer forward: bf16 tap dots, fp32 accumulation.
+    """Fused bf16 equalizer forward: bf16 operands, fp32 accumulation.
 
     The deployment path for QAT formats in the 9–16-bit range
     (`qat.deployment_dtype() == "bfloat16"`). bweights from
-    `cast_weights_bf16` (fp32 weights also accepted — cast in-kernel).
-    Matches the pure-jnp oracle `ref.cnn_eq_bf16` bitwise (shared
-    `conv_valid_taps_bf16` tap math). Shared or per-row stacked weights,
-    like `cnn_eq_fused`.
+    `cast_weights_bf16` (fp32 weights are accepted and rounded to bf16).
+    Matches the pure-jnp oracle `ref.cnn_eq_bf16` bitwise: bf16 products
+    are exact in fp32 and both add them in the same order. Shared or
+    per-row stacked weights, like `cnn_eq_fused`.
     """
-    return _fused_call(_cnn_eq_kernel, x, bweights, strides, tile_m,
-                       interpret, conv_fn=conv_valid_taps_bf16)
+    rounded = tuple((w.astype(jnp.bfloat16).astype(jnp.float32), b)
+                    for w, b in bweights)
+    return _fused_call(_cnn_eq_kernel, x, rounded, strides, tile_m,
+                       interpret, operand=jnp.bfloat16)
 
 
 def quantize_weights_int8(
@@ -369,6 +422,6 @@ def cnn_eq_fused_int8(x: jnp.ndarray,
         _, wf_col = _wformat_cols(wi, wf)
         scale = np.broadcast_to(np.exp2(-(wf_col + af)).reshape(-1),
                                 (c_out,)).astype(np.float32)
-        withscale.append((w, b, jnp.asarray(scale)))
+        withscale.append((w.astype(jnp.int32), b, jnp.asarray(scale)))
     return _fused_call(_cnn_eq_kernel_int8, x, tuple(withscale), strides,
                        tile_m, interpret, formats=formats)

@@ -11,15 +11,20 @@ stream edges — exactly the region the paper's overlap machinery discards.
 tests/test_kernels.py asserts: kernel == ref everywhere, and
 kernel == core-module on the interior.
 
-The convolutions here are TAP-UNROLLED (`conv_valid_taps`): each tap k
-contributes one (C_out, C_in) · (C_in, W) dot, accumulated k = 0 … K-1.
-The Pallas kernel reuses this exact helper on its VMEM tiles — same dots,
-same accumulation order; only the tiling differs, and the contraction is
-over C_in and taps only (never the width axis), so tiling cannot change
-the math. The fused fp32 kernel therefore agrees with this oracle to
-within ~2 ULP (XLA may contract mul+add chains into FMAs differently for
-different program shapes; tests assert atol=5e-6, observed ≤1e-6). The
-int8 path is integer arithmetic and reproduces its oracle EXACTLY.
+The convolutions here are TAP-UNROLLED (`conv_valid_taps`) and spell out
+every product: tap k over a strided slice of the whole stream contributes
+Σ_c w[:, c, k] ⊗ x_c, the channels added c = 0 … C_in-1, and the taps are
+added k = 0 … K-1. There is no dot: a dot leaves its accumulation order and
+precision to the backend (an fp32 dot at DEFAULT precision is one bf16 pass
+on a TPU), while elementwise products and ordered adds mean the same thing
+on the CPU and on the chip. The Pallas kernel states the same sums on a
+polyphase layout of its tiles (unit-stride taps, the layout the TPU compiler
+accepts) — per output element the same products added in the same order —
+so neither layout nor tiling changes the math. The fused fp32 kernel agrees
+with this oracle to within ~2 ULP (XLA may contract a mul+add into an FMA
+on one side and not the other; tests assert atol=5e-6). bf16 products are
+exact in fp32, so the bf16 kernel matches `cnn_eq_bf16` bitwise; the int8
+path is integer arithmetic and reproduces its oracle EXACTLY.
 
 `cnn_eq_quant` is the QAT fake-quant oracle for the int8 datapath: weights
 and per-layer input activations are snapped to their learned fixed-point
@@ -46,46 +51,42 @@ def receptive_halo(kernels: Sequence[int], strides: Sequence[int]) -> int:
 
 def conv_valid_taps(h: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                     stride: int, n_out: int) -> jnp.ndarray:
-    """(C_in, W) ⊛ (C_out, C_in, K) → (C_out, n_out): tap-unrolled dots.
+    """(C_in, W) ⊛ (C_out, C_in, K) → (C_out, n_out) in fp32.
 
-    The shared definition of one equalizer conv layer — used by this oracle
-    AND inside the Pallas kernel, so both accumulate in the same order.
+    The oracle's definition of one equalizer conv layer: for each tap k,
+    the products w[:, c, k] · x[c, k + stride·n] summed over c in order,
+    then the taps summed in order, then the bias.
     """
-    k = w.shape[-1]
-    acc = jnp.zeros((w.shape[0], n_out), jnp.float32)
-    for kk in range(k):
+    h = h.astype(jnp.float32)
+    w = w.astype(jnp.float32)
+    acc = None
+    for kk in range(w.shape[-1]):
         xk = jax.lax.slice(h, (0, kk),
                            (h.shape[0], kk + (n_out - 1) * stride + 1),
                            (1, stride))
-        acc = acc + jax.lax.dot(w[:, :, kk].astype(jnp.float32), xk,
-                                preferred_element_type=jnp.float32)
+        term = None
+        for c in range(h.shape[0]):
+            prod = w[:, c, kk][:, None] * xk[c][None, :]
+            term = prod if term is None else term + prod
+        acc = term if acc is None else acc + term
     return acc + b.astype(jnp.float32)[:, None]
 
 
 def conv_valid_taps_bf16(h: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                          stride: int, n_out: int) -> jnp.ndarray:
-    """bf16 variant of `conv_valid_taps`: bf16 MXU dots, fp32 accumulation.
+    """bf16 variant of `conv_valid_taps`: bf16 operands, fp32 accumulation.
 
-    Inputs and weights are cast to bfloat16 immediately before each tap dot
-    (weights may already be bf16 — the cast is then a no-op); the accumulator,
-    bias add, and the activations BETWEEN layers stay fp32. This is the
-    deployment datapath for QAT formats in the 9–16-bit range
-    (`qat.deployment_dtype() == "bfloat16"`): bf16's 8-bit mantissa covers the
-    learned fraction widths and its exponent covers any integer width, so no
-    clipping/saturation logic is needed. Shared by the pure-jnp oracle
-    (`cnn_eq_bf16`) and the fused Pallas kernel — same dots, same order.
+    Inputs and weights are rounded to bfloat16 before the layer (weights may
+    already be bf16 — the rounding is then a no-op); products, sums, the
+    bias add and the activations BETWEEN layers stay fp32. A bf16·bf16
+    product is exact in fp32. This is the deployment datapath for QAT
+    formats in the 9–16-bit range (`qat.deployment_dtype() == "bfloat16"`):
+    bf16's 8-bit mantissa covers the learned fraction widths and its
+    exponent covers any integer width, so no clipping/saturation logic is
+    needed. The oracle of the fused bf16 kernel (`cnn_eq_bf16`).
     """
-    k = w.shape[-1]
-    hb = h.astype(jnp.bfloat16)
-    wb = w.astype(jnp.bfloat16)
-    acc = jnp.zeros((w.shape[0], n_out), jnp.float32)
-    for kk in range(k):
-        xk = jax.lax.slice(hb, (0, kk),
-                           (hb.shape[0], kk + (n_out - 1) * stride + 1),
-                           (1, stride))
-        acc = acc + jax.lax.dot(wb[:, :, kk], xk,
-                                preferred_element_type=jnp.float32)
-    return acc + b.astype(jnp.float32)[:, None]
+    return conv_valid_taps(h.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                           b, stride, n_out)
 
 
 def _halo_pad(x: jnp.ndarray, kernels: Sequence[int],

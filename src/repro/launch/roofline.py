@@ -1,7 +1,7 @@
 """Roofline analysis from compiled dry-run artifacts (no real hardware).
 
-Hardware constants: TPU v5e-class — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s per ICI link.
+Hardware constants come from `PEAKS`, a table of published per-chip peaks
+keyed by `jax.Device.device_kind`; a device that is not in it is an error.
 
 XLA's `cost_analysis()` visits while-loop bodies ONCE, so a scan-over-layers
 model under-counts by L× (and grad accumulation by accum×). This module
@@ -20,9 +20,9 @@ therefore carries its own small HLO analyzer:
     per-chip bytes-moved estimate.
 
 Terms (seconds, per step, per chip):
-  compute    = flops / PEAK_FLOPS
-  memory     = hbm_bytes / HBM_BW
-  collective = ring_bytes / ICI_BW
+  compute    = flops / peak bf16 FLOP/s
+  memory     = hbm_bytes / peak HBM bytes/s
+  collective = ring_bytes / ICI bytes/s per link
 """
 from __future__ import annotations
 
@@ -30,9 +30,26 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Published per-chip peaks, keyed by `device_kind`. Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM
+# at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 links, 50 GB/s
+# each).
+V5E = "TPU v5 lite"
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {"bf16_flops": 197e12, "int8_ops": 393e12,
+          "hbm_bytes_per_s": 819e9, "ici_link_bytes_per_s": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The published peaks of one chip of `device_kind`; raises for a
+    device that is not in `PEAKS` rather than assuming another's."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1,
@@ -273,18 +290,20 @@ class Roofline:
     model_flops: float = 0.0     # 6·N·D (global, useful work)
     xla_flops: float = 0.0
     xla_bytes: float = 0.0
+    device_kind: str = V5E       # the chip the dry run was compiled for
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(self.device_kind)["bf16_flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / peaks(self.device_kind)["hbm_bytes_per_s"]
 
     @property
     def t_collective(self) -> float:
-        return self.coll.total_ring_bytes / ICI_BW
+        return (self.coll.total_ring_bytes
+                / peaks(self.device_kind)["ici_link_bytes_per_s"])
 
     @property
     def bottleneck(self) -> str:
@@ -306,7 +325,8 @@ class Roofline:
     @property
     def mfu(self) -> float:
         """Model FLOPs utilization at the roofline step time."""
-        total = self.n_chips * PEAK_FLOPS * self.t_step
+        total = (self.n_chips * peaks(self.device_kind)["bf16_flops"]
+                 * self.t_step)
         return self.model_flops / total if total else 0.0
 
     def to_dict(self) -> dict:

@@ -33,17 +33,11 @@ anyway; requantization is idempotent).
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                     # jax ≥ 0.5 top-level export
-    _shard_map = jax.shard_map
-except AttributeError:                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.equalizer import CNNEqConfig
 from ..core.stream_partition import actual_overlap
@@ -134,10 +128,11 @@ def halo_apply(apply_fn: Callable[[jnp.ndarray], jnp.ndarray],
         y = apply_fn(ext)                                     # CNN instance
         return y[0, o_sym:y.shape[1] - o_sym]                 # ORM
 
-    # check_rep=False: no replication rule exists for pallas_call (the fused
-    # backends); all specs here are fully partitioned so nothing is lost.
-    fn = _shard_map(per_device, mesh=mesh, in_specs=P(axis),
-                    out_specs=P(axis), check_rep=False)
+    # check_vma=False: pallas_call (the fused backends) carries no
+    # varying-manual-axes rule; all specs here are fully partitioned so
+    # nothing is lost.
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=P(axis),
+                       out_specs=P(axis), check_vma=False)
     return fn(x)
 
 
@@ -156,6 +151,6 @@ def halo_apply_batched(apply_fn: Callable, x: jnp.ndarray,
         y = apply_fn(ext)
         return y[:, o_sym:y.shape[1] - o_sym]
 
-    fn = _shard_map(per_device, mesh=mesh, in_specs=P(None, axis),
-                    out_specs=P(None, axis), check_rep=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=P(None, axis),
+                       out_specs=P(None, axis), check_vma=False)
     return fn(x)

@@ -16,7 +16,7 @@ The fused kernel computes output position p (one network pass = V_p symbols)
 from the input window  x[p·ts − halo, p·ts + halo]  (ts = V_p·N_os samples
 per pass, halo = half a receptive field in samples), processing positions in
 tiles of `tile_m` with identical per-tile shapes everywhere in the stream.
-Each output element is an independent chain of tap dots over its own window
+Each output element is an independent chain of tap products over its window
 — no cross-position reduction — so an element's value depends ONLY on
 
   (a) its window's sample values, and

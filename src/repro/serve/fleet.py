@@ -763,6 +763,7 @@ class FleetRuntime:
                 workers.append({
                     "worker": w.idx,
                     "device": str(w.device),
+                    "launch_devices": dict(w.batcher.launch_devices),
                     "alive": w.device_lost is None,
                     "reason": (repr(w.device_lost)
                                if w.device_lost is not None else None),

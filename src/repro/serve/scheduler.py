@@ -139,6 +139,7 @@ class LaunchBatch:
     reqs: List[Request]
     x: np.ndarray                   # (B, W) padded stacked input
     fn: Callable[[jnp.ndarray], jnp.ndarray]
+    devices: Tuple[str, ...] = ()   # where execute()'s output landed
 
 
 class TrafficStats:
@@ -251,6 +252,10 @@ class MicroBatcher:
         self.traffic: Dict[Tuple, TrafficStats] = {}
         self.total_requests = 0
         self.launches = 0
+        # str(device) → landed launches whose output lives there: where
+        # the work really ran, which a fleet worker's device handle alone
+        # cannot show
+        self.launch_devices: Counter = Counter()
         # fault-tolerance hooks (serve/recovery.py): an optional
         # deterministic chaos schedule, and the output-sentinel bound
         # (None = no check). `exec_seq` numbers execute ATTEMPTS — the
@@ -417,8 +422,9 @@ class MicroBatcher:
             for r in batch.reqs:         # raised injection never stamps —
                 if r.plan.span is not None:   # the retry's stamps describe
                     r.plan.span.stamp("launch", t_launch)  # the real launch
-        y = batch.fn(jnp.asarray(batch.x))
-        y = np.asarray(jax.block_until_ready(y))
+        y = jax.block_until_ready(batch.fn(jnp.asarray(batch.x)))
+        batch.devices = tuple(sorted(str(d) for d in y.devices()))
+        y = np.asarray(y)
         if self.fault_plan is not None:
             y = self.fault_plan.on_output(idx, y)
         t_landed = self.clock()
@@ -486,6 +492,7 @@ class MicroBatcher:
         self.total_requests += len(reqs)
         self.batch_sizes.append(len(reqs))
         self.launches += 1
+        self.launch_devices.update(batch.devices)
         self._m_requests.inc(len(reqs))
         self._m_launches.inc()
         self._h_occupancy.observe(len(reqs))

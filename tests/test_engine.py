@@ -3,8 +3,9 @@
 Covers the ISSUE-1 acceptance surface:
   * fused_fp32 backend vs the pure-jnp oracle (`ref.cnn_eq`) across the two
     DOP operating points (equalizer_ht / equalizer_lp) and extra topologies,
-    odd stream lengths, and tile-boundary cases — ≤2-ULP agreement (the
-    kernels share `conv_valid_taps`, so only XLA FMA contraction differs);
+    odd stream lengths, and tile-boundary cases — ≤2-ULP agreement (kernel
+    and oracle add the same products in the same order, so only XLA FMA
+    contraction differs);
   * fused_int8 backend vs the QAT fake-quant reference — within one
     accumulation LSB (observed: exact, integer arithmetic);
   * backend equivalence through `partitioned_apply` — the merged stream is
@@ -148,7 +149,8 @@ def test_int8_kernel_rejects_wide_activation_formats():
     eq.CNNEqConfig(layers=4, kernel=15, channels=4, v_parallel=4),
 ])
 def test_fused_bf16_matches_oracle(cfg):
-    """bf16 kernel and oracle share conv_valid_taps_bf16 → bitwise."""
+    """bf16 products are exact in fp32 and kernel and oracle add them in
+    the same order → bitwise."""
     engine, folded = _engine(cfg, "fused_bf16", tile_m=16)
     weights = tuple((l["w"], l["b"]) for l in folded["conv"])
     strides = tuple(s for _, _, s in cfg.layer_specs())

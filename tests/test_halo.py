@@ -111,11 +111,17 @@ def test_halo_apply_int8_engine_exchanges_int8(repo_src):
                 quant=(3, 4))
         from jax.sharding import PartitionSpec as P
         mesh4 = jax.make_mesh((8,), ("data",))
-        jaxpr = jax.make_jaxpr(halo._shard_map(
+        jaxpr = jax.make_jaxpr(jax.shard_map(
             lambda c: body(c)[0], mesh=mesh4, in_specs=P("data"),
-            out_specs=P("data"), check_rep=False))(x)
-        perm_dtypes = {str(e.invars[0].aval.dtype)
-                       for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+            out_specs=P("data"), check_vma=False))(x)
+        def eqns(j):                   # every equation, sub-jaxprs too
+            for e in j.eqns:
+                yield e
+                for v in e.params.values():
+                    sub = getattr(v, "jaxpr", v)
+                    if hasattr(sub, "eqns"):
+                        yield from eqns(sub)
+        perm_dtypes = {str(e.invars[0].aval.dtype) for e in eqns(jaxpr.jaxpr)
                        if e.primitive.name == "ppermute"}
         assert perm_dtypes == {"int8"}, perm_dtypes
         print("INT8-HALO-OK")
@@ -128,7 +134,7 @@ def test_halo_exchange_unit(repo_src):
     out = run_subprocess_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.halo import _shard_map, halo_exchange
+        from repro.parallel.halo import halo_exchange
 
         mesh = jax.make_mesh((4,), ("data",))
         x = jnp.arange(32, dtype=jnp.float32)          # 8 per device
@@ -136,8 +142,8 @@ def test_halo_exchange_unit(repo_src):
         def f(c):
             return halo_exchange(c, 3, "data")
 
-        y = _shard_map(f, mesh=mesh, in_specs=P("data"),
-                       out_specs=P("data"))(x)
+        y = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                          out_specs=P("data"), check_vma=False)(x)
         y = np.asarray(y).reshape(4, 14)
         # device 1 holds [8..16); halo = [5,6,7] + [16,17,18]
         np.testing.assert_array_equal(y[1][:3], [5, 6, 7])
@@ -156,7 +162,6 @@ def test_grad_compression_psum(repo_src):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.optim import grad_comp
-        from repro.parallel.halo import _shard_map
 
         mesh = jax.make_mesh((4,), ("pod",))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 256))
@@ -167,8 +172,9 @@ def test_grad_compression_psum(repo_src):
             return mean["w"][None], new_err["w"][None]
 
         err0 = jnp.zeros((4, 256))
-        mean, err1 = _shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                                out_specs=(P("pod"), P("pod")))(g, err0)
+        mean, err1 = jax.shard_map(f, mesh=mesh,
+                                   in_specs=(P("pod"), P("pod")),
+                                   out_specs=(P("pod"), P("pod")))(g, err0)
         want = jnp.mean(g, axis=0)
         got = np.asarray(mean).reshape(4, 256)[0]
         # int8 quantization error is bounded by scale/2 per pod

@@ -119,6 +119,15 @@ def test_cnn_eq_tile_invariance():
                                    rtol=1e-4, atol=1e-4)
 
 
+def test_cnn_eq_rejects_nonpositive_tile():
+    """A tile the kernel cannot use raises instead of being resized."""
+    cfg = eq.CNNEqConfig()
+    _, _, folded = _folded(cfg)
+    with pytest.raises(ValueError, match="tile_m"):
+        cnn_eq_fused(jnp.zeros((1, 256)), cnn_ops.weights_of(folded),
+                     cnn_ops.strides_of(cfg), tile_m=0, interpret=True)
+
+
 # ---------------------------------------------------------------------------
 # quantization kernel
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@
     (including chunks smaller than the receptive field) asserting
     serve output == offline engine output per backend — BITWISE for the
     fused fp32/bf16/int8 datapaths; ≤2 ULP for "ref" (the pure-jnp oracle's
-    dot widths depend on stream length, so XLA may contract differently).
+    op widths depend on stream length, so XLA may contract differently).
     The sweep runs under BOTH drivers: the synchronous `ServeRuntime` and
     the threaded `AsyncServeRuntime` (same chunker, same stacked launches —
     only the driving loop differs);

@@ -232,9 +232,21 @@ def test_roofline_terms_and_bottleneck():
     r = rl.Roofline(flops=197e12, hbm_bytes=0.0, coll=coll, n_chips=4,
                     model_flops=4 * 197e12 * 0.5)
     assert r.t_compute == pytest.approx(1.0)
-    assert r.t_collective == pytest.approx(1e9 / rl.ICI_BW)
+    assert r.t_collective == pytest.approx(
+        1e9 / rl.peaks(rl.V5E)["ici_link_bytes_per_s"])
     assert r.bottleneck == "compute"
     assert r.mfu == pytest.approx(0.5)
+
+
+def test_roofline_peaks_table_rejects_unknown_device():
+    assert rl.peaks("TPU v5 lite")["int8_ops"] == 393e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        rl.peaks("cpu")
+    coll = rl.CollectiveStats({}, {}, {})
+    r = rl.Roofline(flops=1.0, hbm_bytes=1.0, coll=coll, n_chips=1,
+                    device_kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        r.t_compute
 
 
 def test_straggler_warmup_never_flags_and_is_excluded_from_quantiles():
