@@ -27,8 +27,7 @@ HARD host-independent criterion (`criteria.link_ok`) with three parts:
 
 All three parts are deterministic under the fixed seeds — `--check`
 fails hard if any breaks. No throughput rates are tracked (estimation
-is host-side numpy; its cost is covered by bench_obs's tracing-tax
-ratio).
+is host-side numpy).
 """
 from __future__ import annotations
 
@@ -64,7 +63,7 @@ CORR_FLOOR = 0.8           # est-vs-true SNR Pearson corr over the bursts
 DROP_FLOOR_DB = 2.0        # the 4 dB true ramp must show as >= this
 SLO_MARGIN_DB = 2.0        # breach threshold below the pre-drift estimate
 
-# bitwise-parity workload (mirrors bench_obs)
+# bitwise-parity workload
 INT8_FMT = tuple((2, 5, 3, 4) for _ in range(CFG.layers))
 PAR_SYMS = 480
 PAR_CHUNK = 120

@@ -14,8 +14,6 @@ One benchmark per paper table/figure (see DESIGN.md §6):
     bench_fault     robustness: chaos-gated failover → BENCH_fault.json
     bench_fleet     robustness: device-loss migration on a 2-worker fleet
                              → BENCH_fleet.json
-    bench_obs       observability: tracing tax + span integrity
-                             → BENCH_obs.json
     bench_net       wire parity: packetized data+control plane
                              → BENCH_net.json
     bench_link      signal health: link estimators + SLO closed loop
@@ -36,17 +34,14 @@ the repo root — after normalizing out the
 uniform host-speed drift per gate group (geomean over shared keys), so
 only RELATIVE per-path regressions fire the gate (default tol: 10% on
 accelerators, 35% on interpret-mode CPU hosts — see `_default_tol`). The
-adapt, fault, fleet and obs gates additionally enforce HARD,
+adapt, fault and fleet gates additionally enforce HARD,
 host-independent criteria: the drift-recovery claim
 (`criteria.recovery_ok` in `BENCH_adapt.json`), the chaos-recovery claim
 (`criteria.recovery_ok` in `BENCH_fault.json` — bitwise zero-loss
 failover under injected faults), the device-loss-migration claim
 (`criteria.fleet_recovery_ok` in `BENCH_fleet.json` — a worker killed
 mid-stream, every stream migrated bitwise with zero loss and zero
-poisoning), and the observability claim (`criteria.overhead_ok` in
-`BENCH_obs.json` — tracing ON keeps the ON/OFF throughput ratio above
-its floor, stays bitwise, and seals exactly one complete span per
-emitted chunk), and the wire-parity claim (`criteria.net_ok` in
+poisoning), and the wire-parity claim (`criteria.net_ok` in
 `BENCH_net.json` — symbols served through the packetized
 NetIngress→runtime→NetEgress path over a reordering+duplicating
 loopback wire stay bitwise vs offline, exactly-once, with the control
@@ -56,12 +51,12 @@ channel SNR ramp, an SLO breach latches during quality degradation and
 triggers an event-driven fine-tune whose promotion retires the alert,
 and serving with link estimation + SLOs + tracing ON stays bitwise vs
 offline on every fused backend) are deterministic under their fixed
-seeds, so their failure is never noise. The fault, fleet, obs, net and
+seeds, so their failure is never noise. The fault, fleet, net and
 link gates carry no throughput rates at all — they are purely the hard
 criteria.
 Compare like with like: the committed baseline must come from the same
 host class AND be recorded in the gate's in-process order
-(`--only engine serve adapt fault fleet obs net link`); CPU hosts run
+(`--only engine serve adapt fault fleet net link`); CPU hosts run
 the kernels in interpret mode.
 """
 from __future__ import annotations
@@ -78,7 +73,7 @@ from repro.compile_cache import enable_compile_cache
 
 from . import (bench_adapt, bench_dop, bench_dse, bench_engine,
                bench_fault, bench_fleet, bench_link, bench_net,
-               bench_obs, bench_platform, bench_proakis, bench_quant,
+               bench_platform, bench_proakis, bench_quant,
                bench_roofline, bench_serve, bench_stream, bench_timing)
 from .common import REPORT_DIR
 
@@ -162,28 +157,6 @@ def _fleet_criteria(rep: dict):
             f"bitwise={crit.get('bitwise')} "
             f"sessions_poisoned={crit.get('sessions_poisoned')} "
             f"device_faults_fired={crit.get('device_faults_fired')})"]
-
-
-def _obs_rates(rep: dict) -> dict:
-    """The obs gate tracks NO absolute rates — the tracing tax is the
-    ON/OFF ratio inside the hard criterion below."""
-    return {}
-
-
-def _obs_criteria(rep: dict):
-    """Hard (host-independent) gate on the fresh obs report: tracing must
-    stay nearly free (ON/OFF throughput ratio above the floor), must not
-    change a single output bit, and every emitted chunk must carry exactly
-    one complete span. The ratio self-normalizes host speed; the bitwise
-    and span checks are deterministic under the fixed seeds."""
-    crit = rep.get("criteria", {})
-    if crit.get("overhead_ok", False):
-        return []
-    return [f"obs: observability criterion failed "
-            f"(overhead {crit.get('overhead_x', 0.0):.2f}x must be >= "
-            f"{crit.get('overhead_floor', 0.5)}, "
-            f"bitwise={crit.get('bitwise')} "
-            f"trace_complete={crit.get('trace_complete')})"]
 
 
 def _net_rates(rep: dict) -> dict:
@@ -300,9 +273,6 @@ def check(tol: float | None = None) -> int:
         ("fleet", REPO_ROOT / "BENCH_fleet.json",
          lambda: bench_fleet.run(out_path=None), _fleet_rates,
          _fleet_criteria),
-        ("obs", REPO_ROOT / "BENCH_obs.json",
-         lambda: bench_obs.run(out_path=None), _obs_rates,
-         _obs_criteria),
         ("net", REPO_ROOT / "BENCH_net.json",
          lambda: bench_net.run(out_path=None), _net_rates,
          _net_criteria),
@@ -410,7 +380,6 @@ def main(argv=None) -> int:
         ("adapt", lambda: bench_adapt.run()),
         ("fault", lambda: bench_fault.run()),
         ("fleet", lambda: bench_fleet.run()),
-        ("obs", lambda: bench_obs.run()),
         ("net", lambda: bench_net.run()),
         ("link", lambda: bench_link.run()),
         ("stream", lambda: bench_stream.run()),

@@ -58,21 +58,25 @@ def split_with_overlap(x_samples: jnp.ndarray, n_inst: int, o_act: int,
 
     x_samples: (S·N_os,) → (n_inst, (ℓ_inst + 2·o_act)·N_os)
     Stream edges are zero-padded (the FPGA pipeline likewise starts cold).
+    Its ops carry the named scope `partition` in the device profile.
     """
     total = x_samples.shape[0]
     l_inst_samp = total // n_inst
     o_samp = o_act * n_os
-    xp = jnp.pad(x_samples, (o_samp, o_samp))
-    starts = jnp.arange(n_inst) * l_inst_samp
-    idx = starts[:, None] + jnp.arange(l_inst_samp + 2 * o_samp)[None, :]
-    return xp[idx]
+    with jax.named_scope("partition"):
+        xp = jnp.pad(x_samples, (o_samp, o_samp))
+        starts = jnp.arange(n_inst) * l_inst_samp
+        idx = starts[:, None] + jnp.arange(l_inst_samp + 2 * o_samp)[None, :]
+        return xp[idx]
 
 
 def merge_with_overlap_removal(chunks_syms: jnp.ndarray, o_act: int
                                ) -> jnp.ndarray:
-    """MSM + ORM: drop o_act symbols at each side of each chunk, concat."""
-    kept = chunks_syms[:, o_act:chunks_syms.shape[1] - o_act]
-    return kept.reshape(-1)
+    """MSM + ORM: drop o_act symbols at each side of each chunk, concat
+    (named scope `merge`)."""
+    with jax.named_scope("merge"):
+        kept = chunks_syms[:, o_act:chunks_syms.shape[1] - o_act]
+        return kept.reshape(-1)
 
 
 def partitioned_apply(engine, x_samples: jnp.ndarray, n_inst: int,

@@ -213,9 +213,14 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return bool(interpret)
 
 
-def _fused_call(kernel_body, x, weights, strides, tile_m, interpret,
+def _fused_call(kernel_body, x, weights, strides, tile_m, interpret, name,
                 **kernel_kwargs):
     """Shared grid/BlockSpec plumbing for all fused kernel bodies.
+
+    `name` is the kernel's stable name in compiled programs and device
+    profiles (`cnn_eq_fused_{fp32,bf16,int8}`, one per datapath). The
+    wrapper's own ops carry the named scopes `tile_windows` (the per-tile
+    input windows) and `interleave` (channel-major tiles to symbol order).
 
     Weights are either SHARED — w: (C_out, C_in, K) broadcast to every batch
     row — or STACKED per row — w: (B, C_out, C_in, K), b: (B, C_out), batch
@@ -265,12 +270,14 @@ def _fused_call(kernel_body, x, weights, strides, tile_m, interpret,
     # samples past the last window feed no kept position.
     reach = pl.cdiv(in_cols - tile_m, tile_m)
     padded = (n_tiles + reach) * tile_m * total_stride
-    xp = jnp.pad(x, ((0, 0), (halo, max(0, padded - width - halo))))
-    blocks = xp[:, :padded].reshape(batch, n_tiles + reach, tile_m,
-                                    total_stride)
-    xp = jnp.concatenate([blocks[:, j:j + n_tiles] for j in range(reach + 1)],
-                         axis=2)[:, :, :in_cols]
-    xp = jnp.swapaxes(xp, 2, 3)                    # (B, n_tiles, T, in_cols)
+    with jax.named_scope("tile_windows"):
+        xp = jnp.pad(x, ((0, 0), (halo, max(0, padded - width - halo))))
+        blocks = xp[:, :padded].reshape(batch, n_tiles + reach, tile_m,
+                                        total_stride)
+        xp = jnp.concatenate(
+            [blocks[:, j:j + n_tiles] for j in range(reach + 1)],
+            axis=2)[:, :, :in_cols]
+        xp = jnp.swapaxes(xp, 2, 3)                # (B, n_tiles, T, in_cols)
 
     def full(shape):
         return pl.BlockSpec(shape, lambda ib, it: (0,) * len(shape))
@@ -307,10 +314,13 @@ def _fused_call(kernel_body, x, weights, strides, tile_m, interpret,
         out_shape=jax.ShapeDtypeStruct(
             (batch, n_tiles, v_parallel, tile_m), x.dtype),
         interpret=interpret,
+        name=name,
     )(*flat)
     # (B, n_tiles, V_p, tile_m) → interleave channels: symbol s = m·V_p + c
-    out = jnp.swapaxes(out, 2, 3).reshape(batch, n_tiles * tile_m * v_parallel)
-    return out[:, :n_syms]
+    with jax.named_scope("interleave"):
+        out = jnp.swapaxes(out, 2, 3).reshape(
+            batch, n_tiles * tile_m * v_parallel)
+        return out[:, :n_syms]
 
 
 @functools.partial(jax.jit,
@@ -327,7 +337,7 @@ def cnn_eq_fused(x: jnp.ndarray,
     Output length = W // (V_p·N_os) · V_p.
     """
     return _fused_call(_cnn_eq_kernel, x, weights, strides, tile_m, interpret,
-                       operand=jnp.float32)
+                       "cnn_eq_fused_fp32", operand=jnp.float32)
 
 
 def cast_weights_bf16(
@@ -357,7 +367,7 @@ def cnn_eq_fused_bf16(x: jnp.ndarray,
     rounded = tuple((w.astype(jnp.bfloat16).astype(jnp.float32), b)
                     for w, b in bweights)
     return _fused_call(_cnn_eq_kernel, x, rounded, strides, tile_m,
-                       interpret, operand=jnp.bfloat16)
+                       interpret, "cnn_eq_fused_bf16", operand=jnp.bfloat16)
 
 
 def quantize_weights_int8(
@@ -424,4 +434,4 @@ def cnn_eq_fused_int8(x: jnp.ndarray,
                                 (c_out,)).astype(np.float32)
         withscale.append((w.astype(jnp.int32), b, jnp.asarray(scale)))
     return _fused_call(_cnn_eq_kernel_int8, x, tuple(withscale), strides,
-                       tile_m, interpret, formats=formats)
+                       tile_m, interpret, "cnn_eq_fused_int8", formats=formats)

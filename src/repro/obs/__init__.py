@@ -9,7 +9,9 @@ One instrumentation spine across every layer built in PRs 1-7:
   * `trace`    — per-chunk lifecycle spans (submit -> assemble -> launch ->
     execute -> descatter -> emit) with retries/replays/migrations recorded
     as child events, buffered in a bounded ring, exportable as Chrome
-    `trace_event` JSON (Perfetto-viewable).
+    `trace_event` JSON (Perfetto-viewable); `annotate` puts the serving
+    stack's host phases on the JAX device profiler's timeline as
+    `serve.*` spans.
   * `hub`      — the `Observability` facade (registry + tracer + `Retention`
     policy) that runtimes accept via their `obs=` parameter.
   * `link`     — streaming per-tenant link-quality estimators (decision-
@@ -31,7 +33,7 @@ from .link import LinkEstimate, LinkMonitor
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, Scope,
                       safe_segment)
 from .slo import SloEngine, SloRule
-from .trace import PHASES, ChunkSpan, Tracer
+from .trace import PHASES, ChunkSpan, Tracer, annotate
 
 __all__ = [
     "Observability",
@@ -49,4 +51,5 @@ __all__ = [
     "PHASES",
     "ChunkSpan",
     "Tracer",
+    "annotate",
 ]

@@ -82,6 +82,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..obs import Observability
+from ..obs.trace import annotate, waited
 from ..runtime.straggler import StragglerConfig, StragglerMonitor
 from .pool import EnginePool
 from .recovery import (CorruptOutput, DeviceLost, FaultPlan, LaunchTimeout,
@@ -642,12 +643,12 @@ class FleetRuntime:
         worker. Returns a per-chunk future (None while buffering below an
         emittable position). Never blocks on a worker — queues are
         unbounded and a dead worker's traffic strands for migration."""
-        with self._mutex:
+        with annotate("serve.submit"), waited(self._mutex):
             self._check_running()
             self._absorb_dead_workers()
             if tenant_id not in self._sessions:
                 raise KeyError(f"tenant {tenant_id!r} not open")
-            with self._state:
+            with waited(self._state):
                 s = self._sessions[tenant_id]
                 w = self._homes[tenant_id]
                 s.chunker.push(np.asarray(samples))
@@ -657,12 +658,12 @@ class FleetRuntime:
 
     def finish(self, tenant_id: str) -> Optional[concurrent.futures.Future]:
         """End-of-stream marker: queue the zero-padded tail flush."""
-        with self._mutex:
+        with annotate("serve.submit"), waited(self._mutex):
             self._check_running()
             self._absorb_dead_workers()
             if tenant_id not in self._sessions:
                 raise KeyError(f"tenant {tenant_id!r} not open")
-            with self._state:
+            with waited(self._state):
                 s = self._sessions[tenant_id]
                 w = self._homes[tenant_id]
                 if not s.chunker.finished:
@@ -875,12 +876,12 @@ class FleetRuntime:
             if self._stop.is_set():
                 return
             try:
-                with self._mutex:
+                with annotate("serve.pump"), waited(self._mutex):
                     if self._stop.is_set():
                         return
                     self._absorb_dead_workers()
                     for w in self._healthy():
-                        with self._state:
+                        with waited(self._state):
                             self._dispatch_locked(
                                 w, w.batcher.take_ready())
             except Exception as e:  # noqa: BLE001 — keep the clock alive

@@ -16,6 +16,8 @@ import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Hashable, Optional
 
+from ..obs.trace import annotate
+
 
 class EnginePool:
     """LRU cache of built engines: key → engine.
@@ -74,7 +76,8 @@ class EnginePool:
         if self.fault_plan is not None:
             self.fault_plan.on_build(idx)
         t0 = self.clock()
-        engine = build()                   # slow: outside the lock
+        with annotate("serve.engine_build"):
+            engine = build()               # slow: outside the lock
         if self.build_hook is not None:
             self.build_hook(key, self.clock() - t0)
         with self._lock:

@@ -100,6 +100,7 @@ import numpy as np
 from ..core import autotune as autotune_lib
 from ..core.engine import EqualizerEngine
 from ..obs import Observability
+from ..obs.trace import annotate, waited
 from ..runtime.straggler import StragglerConfig
 from .pool import EnginePool
 from .recovery import (CorruptOutput, DegradationController, FaultPlan,
@@ -319,10 +320,11 @@ class ServeRuntime:
         (max_batch reached, or another group's max_wait expired). Returns
         the queued request (symbols populated once launched) or None when
         the chunk is buffered below one emittable position."""
-        s = self.sessions.get(tenant_id)
-        s.chunker.push(np.asarray(samples))
-        req = self.batcher.enqueue(s)
-        self.batcher.pump()
+        with annotate("serve.submit"):
+            s = self.sessions.get(tenant_id)
+            s.chunker.push(np.asarray(samples))
+            req = self.batcher.enqueue(s)
+            self.batcher.pump()
         return req
 
     def finish(self, tenant_id: str) -> Optional[Request]:
@@ -623,8 +625,8 @@ class AsyncServeRuntime:
         `TenantShedError` while this tenant is load-shed by the
         degradation controller (`degrade_on_slow`) — shed tenants are
         readmitted automatically once launch health returns."""
-        with self._dispatch_mutex:
-            with self._lock:
+        with annotate("serve.submit"), waited(self._dispatch_mutex):
+            with waited(self._lock):
                 self._check_running()
                 s = self.sessions.get(tenant_id)
                 if s.shed:
@@ -642,8 +644,8 @@ class AsyncServeRuntime:
     def finish(self, tenant_id: str) -> Optional[concurrent.futures.Future]:
         """End-of-stream marker: queue the zero-padded tail flush. Returns
         the tail chunk's future (None if the stream had no residue)."""
-        with self._dispatch_mutex:
-            with self._lock:
+        with annotate("serve.submit"), waited(self._dispatch_mutex):
+            with waited(self._lock):
                 self._check_running()
                 s = self.sessions.get(tenant_id)
                 if not s.chunker.finished:
@@ -743,7 +745,8 @@ class AsyncServeRuntime:
         execute."""
         for i, b in enumerate(batches):
             try:
-                self._launch_q.put(b)
+                with annotate("serve.queue_put"):
+                    self._launch_q.put(b)
             except BaseException:
                 with self._lock:
                     for rb in reversed(batches[i:]):
@@ -763,8 +766,8 @@ class AsyncServeRuntime:
             if self._stop.is_set():
                 return
             try:
-                with self._dispatch_mutex:
-                    with self._lock:
+                with annotate("serve.pump"), waited(self._dispatch_mutex):
+                    with waited(self._lock):
                         batches = self._take(self.batcher.take_ready())
                     self._dispatch(batches)
             except Exception as e:  # noqa: BLE001 — keep the clock alive

@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import itertools
 import threading
 import time
 from collections import Counter, deque
@@ -54,11 +55,15 @@ import numpy as np
 
 from ..core.engine import stacked_engine_fn
 from ..obs import Observability
+from ..obs.trace import annotate
 from .chunker import ChunkPlan
 from .recovery import CorruptOutput, output_ok
 from .session import Session
 
 _CONSUMED = np.zeros((0,), np.float32)     # placeholder for launched inputs
+# launch ids: unique in the process, as a profiler trace is (fleet workers
+# and separate runtimes each own a batcher)
+_LAUNCH_IDS = itertools.count()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,13 +138,16 @@ class LaunchBatch:
 
     Assembly snapshots the padded input `x` and the memoized launch fn so
     the device phase touches NO scheduler state — the async launcher thread
-    runs execute() without holding the runtime lock.
+    runs execute() without holding the runtime lock. `launch` is the id
+    that the launch's profiler spans (assemble, execute, descatter) and its
+    requests' chunk spans share.
     """
     key: Tuple                      # the group_key the requests share
     reqs: List[Request]
     x: np.ndarray                   # (B, W) padded stacked input
     fn: Callable[[jnp.ndarray], jnp.ndarray]
     devices: Tuple[str, ...] = ()   # where execute()'s output landed
+    launch: int = -1
 
 
 class TrafficStats:
@@ -216,6 +224,9 @@ class MicroBatcher:
     # full history (unbounded streams would otherwise leak one Request,
     # with its symbols array, per chunk forever)
     COMPLETED_MAX = 8192
+    COUNTERS = ("group_fn_hits", "group_fn_misses", "restacks",
+                "launched_samples", "useful_samples", "h2d_bytes",
+                "d2h_bytes")
 
     def __init__(self, policy: Optional[BatchPolicy] = None,
                  clock: Callable[[], float] = time.perf_counter,
@@ -240,6 +251,9 @@ class MicroBatcher:
         self._h_device = scope.histogram("launch.device_s", window)
         self._h_descatter = scope.histogram("launch.descatter_s", window)
         scope.callback("pending", self.pending)
+        for name in self.COUNTERS:
+            scope.callback(f"{name}_total",
+                           lambda name=name: getattr(self, name))
         scope.callback("latency", self.latency_stats)
         scope.callback("traffic", self.traffic_stats)
         self._groups: Dict[Tuple, List[Request]] = {}
@@ -252,6 +266,17 @@ class MicroBatcher:
         self.traffic: Dict[Tuple, TrafficStats] = {}
         self.total_requests = 0
         self.launches = 0
+        # launch counters (plain ints, registered as serve.<name>_total):
+        # memoized group fn hits/misses, misses that stacked weights,
+        # samples launched (B·W, padding included) and useful (Σ plan
+        # widths) over landed launches, bytes copied each way per attempt
+        self.group_fn_hits = 0
+        self.group_fn_misses = 0
+        self.restacks = 0
+        self.launched_samples = 0
+        self.useful_samples = 0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
         # str(device) → landed launches whose output lives there: where
         # the work really ran, which a fleet worker's device handle alone
         # cannot show
@@ -391,18 +416,21 @@ class MicroBatcher:
     def assemble(self, key: Tuple, reqs: List[Request]) -> LaunchBatch:
         """Host phase 1: pad the requests' plans to one width bucket, stack
         them into the (B, W) launch input, bind the memoized group fn."""
-        if self.tracer.enabled:
-            t = self.clock()
-            for r in reqs:
-                if r.plan.span is not None:
-                    r.plan.span.stamp("assemble", t)
-        engines = [r.session.engine for r in reqs]
-        fn = self._group_fn(engines)
-        width = self._bucket_width(reqs)
-        x = np.zeros((len(reqs), width), np.float32)
-        for i, r in enumerate(reqs):
-            x[i, :r.plan.width] = r.plan.data      # right zero-pad = offline
-        return LaunchBatch(key=key, reqs=reqs, x=x, fn=fn)
+        launch = next(_LAUNCH_IDS)
+        with annotate("serve.assemble", launch=launch, rows=len(reqs)):
+            if self.tracer.enabled:
+                t = self.clock()
+                for r in reqs:
+                    if r.plan.span is not None:
+                        r.plan.span.stamp("assemble", t)
+                        r.plan.span.launch = launch
+            engines = [r.session.engine for r in reqs]
+            fn = self._group_fn(engines)
+            width = self._bucket_width(reqs)
+            x = np.zeros((len(reqs), width), np.float32)
+            for i, r in enumerate(reqs):
+                x[i, :r.plan.width] = r.plan.data  # right zero-pad = offline
+        return LaunchBatch(key=key, reqs=reqs, x=x, fn=fn, launch=launch)
 
     def execute(self, batch: LaunchBatch) -> np.ndarray:
         """Device phase: ONE stacked fused-kernel launch, blocking until
@@ -412,30 +440,39 @@ class MicroBatcher:
         `FaultPlan` may raise/delay before the dispatch or corrupt the
         landed output at its scheduled indices (retries and failover
         replays consume FRESH indices, so an injected fault fires once)."""
-        idx, self.exec_seq = self.exec_seq, self.exec_seq + 1
-        if self.fault_plan is not None:
-            if self.worker_index is not None:
-                self.fault_plan.on_worker(self.worker_index, idx)
-            self.fault_plan.on_execute(idx)
-        t_launch = self.clock()
-        if self.tracer.enabled:          # stamp AFTER the fault hooks so a
-            for r in batch.reqs:         # raised injection never stamps —
-                if r.plan.span is not None:   # the retry's stamps describe
-                    r.plan.span.stamp("launch", t_launch)  # the real launch
-        y = jax.block_until_ready(batch.fn(jnp.asarray(batch.x)))
-        batch.devices = tuple(sorted(str(d) for d in y.devices()))
-        y = np.asarray(y)
-        if self.fault_plan is not None:
-            y = self.fault_plan.on_output(idx, y)
-        t_landed = self.clock()
-        self._h_device.observe(t_landed - t_launch)
-        if self.tracer.enabled:
+        with annotate("serve.execute", launch=batch.launch):
+            idx, self.exec_seq = self.exec_seq, self.exec_seq + 1
+            if self.fault_plan is not None:
+                if self.worker_index is not None:
+                    self.fault_plan.on_worker(self.worker_index, idx)
+                self.fault_plan.on_execute(idx)
+            t_launch = self.clock()
+            if self.tracer.enabled:      # stamp AFTER the fault hooks so a
+                for r in batch.reqs:     # raised injection never stamps —
+                    if r.plan.span is not None:   # the retry's stamps
+                        r.plan.span.stamp("launch", t_launch)  # are real
+            with annotate("serve.h2d"):
+                x = jnp.asarray(batch.x)
+            self.h2d_bytes += batch.x.nbytes
+            with annotate("serve.dispatch"):
+                y = batch.fn(x)
+            with annotate("serve.wait"):
+                y = jax.block_until_ready(y)
+            batch.devices = tuple(sorted(str(d) for d in y.devices()))
+            with annotate("serve.d2h"):
+                y = np.asarray(y)
+            self.d2h_bytes += y.nbytes
+            if self.fault_plan is not None:
+                y = self.fault_plan.on_output(idx, y)
+            t_landed = self.clock()
+            self._h_device.observe(t_landed - t_launch)
+            if self.tracer.enabled:
+                for r in batch.reqs:
+                    if r.plan.span is not None:
+                        r.plan.span.stamp("execute", t_landed)
             for r in batch.reqs:
-                if r.plan.span is not None:
-                    r.plan.span.stamp("execute", t_landed)
-        for r in batch.reqs:
-            r.t_launch = t_launch
-        return y
+                r.t_launch = t_launch
+            return y
 
     def descatter(self, batch: LaunchBatch, y: np.ndarray) -> None:
         """Host phase 2: slice each tenant's emitted rows out of the
@@ -447,6 +484,10 @@ class MicroBatcher:
         intact (inputs unconsumed, futures pending, nothing appended), so
         the caller can requeue or replay it exactly like a failed launch —
         quarantine instead of emitting garbage."""
+        with annotate("serve.descatter", launch=batch.launch):
+            self._descatter(batch, y)
+
+    def _descatter(self, batch: LaunchBatch, y: np.ndarray) -> None:
         if self.sentinel_limit is not None and not output_ok(
                 y, self.sentinel_limit):
             raise CorruptOutput(
@@ -455,6 +496,7 @@ class MicroBatcher:
                 f"batch of {len(batch.reqs)}")
         t_done = self.clock()
         reqs = batch.reqs
+        useful = sum(r.plan.width for r in reqs)   # before release
         for i, r in enumerate(reqs):
             vp = r.session.v_parallel
             syms = y[i, r.plan.skip * vp:(r.plan.skip + r.plan.n_emit) * vp]
@@ -489,6 +531,8 @@ class MicroBatcher:
         skey = reqs[0].session.engine.tune_key()
         self.traffic.setdefault(skey, TrafficStats()).record(
             len(reqs), batch.x.shape[1])
+        self.launched_samples += batch.x.size
+        self.useful_samples += useful
         self.total_requests += len(reqs)
         self.batch_sizes.append(len(reqs))
         self.launches += 1
@@ -570,12 +614,22 @@ class MicroBatcher:
         """Memoized stacked launch fn: steady-state round-robin traffic
         re-batches the SAME engines in the SAME order every round, so the
         per-launch weight re-stack (and its host→device transfer) is paid
-        once per tenant set, not once per launch."""
+        once per tenant set, not once per launch. A miss on two or more
+        engines of a fused backend is a restack: the weight stack is built
+        by eager device ops (`serve.restack` span, `restacks` counter)."""
         key = tuple(id(e) for e in engines)
         hit = self._fn_cache.get(key)
         if hit is not None:
+            self.group_fn_hits += 1
             return hit[1]
-        fn = stacked_engine_fn(engines)
+        self.group_fn_misses += 1
+        if len(engines) > 1 and engines[0].backend != "ref":
+            # a miss that stacks the tenants' weights on the device
+            self.restacks += 1
+            with annotate("serve.restack", rows=len(engines)):
+                fn = stacked_engine_fn(engines)
+        else:
+            fn = stacked_engine_fn(engines)
         self._fn_cache[key] = (list(engines), fn)
         while len(self._fn_cache) > self.FN_CACHE_MAX:
             self._fn_cache.pop(next(iter(self._fn_cache)))

@@ -19,6 +19,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.configs import equalizer_ht as HT
 from repro.core import equalizer as eq
+from repro.core import stream_partition as sp
 from repro.core.engine import EqualizerEngine
 from repro.kernels.cnn_eq import (cast_weights_bf16, cnn_eq_fused,
                                   cnn_eq_fused_bf16, cnn_eq_fused_int8,
@@ -128,3 +129,25 @@ def test_halo_apply_batched_compiles_on_four_chips(topo):
     text = _compile_has_kernel(
         lambda v: halo.halo_apply_batched(engine, v, CFG, mesh), x)
     assert "collective-permute" in text
+
+
+def test_partitioned_ht_unit_names_its_scopes_in_the_compiled_program(
+        one_chip):
+    """The device profile attributes op time by op name metadata: the
+    partition gather keeps its scope through fusion, and the kernel its
+    per-datapath name."""
+    import re
+    engine = EqualizerEngine.from_folded(
+        eq.fold_bn(eq.init(jax.random.PRNGKey(4), CFG),
+                   eq.init_bn_state(CFG), CFG),
+        CFG, backend="fused_int8", formats=INT8_FMT, tile_m=128,
+        interpret=False)
+    x = jax.ShapeDtypeStruct((HT.N_INSTANCES * HT.L_INST * CFG.n_os,),
+                             jnp.float32, sharding=one_chip)
+    text = _compile_has_kernel(
+        lambda v: sp.partitioned_apply(engine, v, HT.N_INSTANCES, CFG), x)
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert any(n.endswith("/partition/gather") for n in op_names)
+    assert any("/tile_windows/" in n for n in op_names)
+    assert any(n.endswith("/cnn_eq_fused_int8/pallas_call")
+               for n in op_names)
