@@ -58,16 +58,36 @@ def split_with_overlap(x_samples: jnp.ndarray, n_inst: int, o_act: int,
 
     x_samples: (S·N_os,) → (n_inst, (ℓ_inst + 2·o_act)·N_os)
     Stream edges are zero-padded (the FPGA pipeline likewise starts cold).
+
+    Chunk i is samples [i·ℓ − o, (i+1)·ℓ + o) of the stream (ℓ = ℓ_inst·N_os,
+    o = o_act·N_os), so it reaches r = ⌈o/ℓ⌉ blocks to each side. The
+    stream is zero-padded by r·ℓ per side and reshaped into (n_inst + 2r, ℓ)
+    blocks; row i of the view blocks[j : j + n_inst] is samples
+    [(i − r + j)·ℓ, (i − r + j + 1)·ℓ). The chunk is the concat over
+    j = 0..2r of what each view holds inside it: the last o − (r−1)·ℓ
+    samples of view 0, the middle views whole, the first o − (r−1)·ℓ of
+    view 2r. Every offset is static: a pad, a reshape, slices and one
+    concat, with no index array and no gather.
+    Samples past n_inst·ℓ (a stream that does not divide) feed only the
+    last chunk's right overlap, as far as it reaches.
     Its ops carry the named scope `partition` in the device profile.
     """
     total = x_samples.shape[0]
-    l_inst_samp = total // n_inst
+    l_samp = total // n_inst
     o_samp = o_act * n_os
+    reach = -(-o_samp // l_samp)
     with jax.named_scope("partition"):
-        xp = jnp.pad(x_samples, (o_samp, o_samp))
-        starts = jnp.arange(n_inst) * l_inst_samp
-        idx = starts[:, None] + jnp.arange(l_inst_samp + 2 * o_samp)[None, :]
-        return xp[idx]
+        # negative right padding crops a tail the chunks never reach
+        tail = total - n_inst * l_samp
+        xp = jax.lax.pad(x_samples, jnp.zeros((), x_samples.dtype),
+                         [(reach * l_samp, reach * l_samp - tail, 0)])
+        blocks = xp.reshape(n_inst + 2 * reach, l_samp)
+        lo = reach * l_samp - o_samp           # chunk start in view 0
+        hi = lo + l_samp + 2 * o_samp
+        return jnp.concatenate(
+            [blocks[j:j + n_inst, max(lo - j * l_samp, 0):
+                    min(hi - j * l_samp, l_samp)]
+             for j in range(2 * reach + 1)], axis=1)
 
 
 def merge_with_overlap_removal(chunks_syms: jnp.ndarray, o_act: int

@@ -99,6 +99,43 @@ def test_partitioned_equals_unsplit_interior(n_inst):
                                    rtol=1e-4, atol=1e-4)
 
 
+def _gather_split(x, n_inst, o_act, n_os):
+    """The split as an index gather: chunk i is the zero-padded stream from
+    i·ℓ, ℓ + 2o samples long."""
+    l_samp, o_samp = x.shape[0] // n_inst, o_act * n_os
+    xp = np.pad(x, (o_samp, o_samp))
+    starts = np.arange(n_inst) * l_samp
+    return xp[starts[:, None] + np.arange(l_samp + 2 * o_samp)]
+
+
+@pytest.mark.parametrize("n_inst,l_inst,o_act,tail", [
+    (64, 7320, 1024, 0),      # the HT deployment unit (reach 1)
+    (1, 512, 80, 0),          # one instance: zero overlap both sides
+    (16, 20, 96, 0),          # overlap past several neighbours (reach 5)
+    (8, 16, 128, 0),          # reach 8
+    (2, 512, 96, 0),          # test_partitioned_equals_unsplit_interior
+    (4, 512, 128, 0),
+    (8, 512, 128, 0),
+    (4, 1024, 128, 0),        # test_partition_ber_flat_across_borders
+    (8, 256, 128, 0),         # test_halo
+    (8, 1024, 128, 0),        # test_engine's backend equivalence
+    (4, 100, 40, 3),          # a stream that does not divide: tail samples
+    (4, 100, 40, 90),         # feed only the last chunk's right overlap
+], ids=lambda v: str(v))
+def test_split_with_overlap_matches_gather_bitwise(n_inst, l_inst, o_act,
+                                                   tail):
+    """The static-slice split is pure data movement: it equals the index
+    gather exactly, for any reach r = ⌈o/ℓ⌉."""
+    n_os = PAPER_CFG.n_os
+    rng = np.random.default_rng(n_inst * 7919 + l_inst + o_act + tail)
+    x = rng.standard_normal(n_inst * l_inst * n_os + tail).astype(np.float32)
+    got = np.asarray(jax.jit(
+        lambda v: sp.split_with_overlap(v, n_inst, o_act, n_os))(x))
+    want = _gather_split(x, n_inst, o_act, n_os)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(got, want)
+
+
 def test_partition_ber_flat_across_borders():
     """The paper's Fig-9 property: BER is not elevated at chunk borders."""
     cfg = PAPER_CFG
