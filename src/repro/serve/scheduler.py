@@ -335,6 +335,13 @@ class MicroBatcher:
 
     # -- launch phases (assemble → execute → descatter) --------------------
 
+    def _annotate(self, name: str, **args):
+        """`annotate` with the fleet worker's index as arg `worker`, where
+        this batcher belongs to one."""
+        if self.worker_index is not None:
+            args["worker"] = self.worker_index
+        return annotate(name, **args)
+
     def take_ready(self, now: Optional[float] = None,
                    force: bool = False) -> List[LaunchBatch]:
         """Pop and ASSEMBLE every policy-ready batch (all, if force).
@@ -417,7 +424,8 @@ class MicroBatcher:
         """Host phase 1: pad the requests' plans to one width bucket, stack
         them into the (B, W) launch input, bind the memoized group fn."""
         launch = next(_LAUNCH_IDS)
-        with annotate("serve.assemble", launch=launch, rows=len(reqs)):
+        with self._annotate("serve.assemble", launch=launch,
+                            rows=len(reqs)):
             if self.tracer.enabled:
                 t = self.clock()
                 for r in reqs:
@@ -440,7 +448,7 @@ class MicroBatcher:
         `FaultPlan` may raise/delay before the dispatch or corrupt the
         landed output at its scheduled indices (retries and failover
         replays consume FRESH indices, so an injected fault fires once)."""
-        with annotate("serve.execute", launch=batch.launch):
+        with self._annotate("serve.execute", launch=batch.launch):
             idx, self.exec_seq = self.exec_seq, self.exec_seq + 1
             if self.fault_plan is not None:
                 if self.worker_index is not None:
@@ -484,7 +492,7 @@ class MicroBatcher:
         intact (inputs unconsumed, futures pending, nothing appended), so
         the caller can requeue or replay it exactly like a failed launch —
         quarantine instead of emitting garbage."""
-        with annotate("serve.descatter", launch=batch.launch):
+        with self._annotate("serve.descatter", launch=batch.launch):
             self._descatter(batch, y)
 
     def _descatter(self, batch: LaunchBatch, y: np.ndarray) -> None:
@@ -626,7 +634,7 @@ class MicroBatcher:
         if len(engines) > 1 and engines[0].backend != "ref":
             # a miss that stacks the tenants' weights on the device
             self.restacks += 1
-            with annotate("serve.restack", rows=len(engines)):
+            with self._annotate("serve.restack", rows=len(engines)):
                 fn = stacked_engine_fn(engines)
         else:
             fn = stacked_engine_fn(engines)

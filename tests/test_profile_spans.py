@@ -9,6 +9,8 @@ that device profiles attribute op time by.
     documented `serve.*` span is in the profile, nested as documented,
     and one launch id joins assemble, execute, descatter and the chunks'
     `ChunkSpan`s;
+  * a two-worker `FleetRuntime` under the profiler: each worker's launch
+    spans carry its index as `worker` (a one-chip runtime's carry none);
   * the lowered `partitioned_apply` carries the scopes `partition`,
     `tile_windows`, `interleave` and `merge`, and the kernel its stable
     per-datapath name.
@@ -26,8 +28,8 @@ from repro.core import equalizer as eq
 from repro.core import stream_partition as sp
 from repro.core.engine import EqualizerEngine
 from repro.obs import Observability, annotate
-from repro.serve import AsyncServeRuntime, BatchPolicy, ServeRuntime, \
-    TenantSpec
+from repro.serve import AsyncServeRuntime, BatchPolicy, FleetRuntime, \
+    ServeRuntime, TenantSpec
 
 CFG = eq.CNNEqConfig()
 INT8_FMT = tuple((2, 5, 3, 4) for _ in range(CFG.layers))
@@ -176,6 +178,8 @@ def test_profiler_spans_nest_and_share_launch_ids(tmp_path):
     for line in lines:
         for name, _, _, stats in line:
             by_name[name].append(stats)
+    # a one-chip runtime's spans name no fleet worker
+    assert not any("worker" in st for sts in by_name.values() for st in sts)
     rows = {st["launch"]: st["rows"] for st in by_name["serve.assemble"]}
     for phase in ("serve.execute", "serve.descatter"):
         ids = [st["launch"] for st in by_name[phase]]
@@ -186,6 +190,43 @@ def test_profiler_spans_nest_and_share_launch_ids(tmp_path):
     exported = [ev for ev in obs.chrome_trace()["traceEvents"]
                 if ev["name"].startswith("chunk ")]
     assert {ev["args"]["launch"] for ev in exported} == set(rows)
+
+
+def test_fleet_launch_spans_carry_the_worker_index(tmp_path):
+    rng = np.random.default_rng(2)
+    with FleetRuntime(n_workers=2, policy=BatchPolicy(max_batch=2,
+                                                      max_wait_s=0.02),
+                      obs=Observability(tracing=True)) as rt:
+        for i in range(4):
+            rt.open(_spec(f"t{i}", i))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for _ in range(2):
+                for i in range(4):
+                    rt.submit(f"t{i}", _chunk(rng))
+            rt.drain()
+        finally:
+            jax.profiler.stop_trace()
+        homes = rt.stats()["placement"]
+    assert sorted(homes.values()) == [0, 0, 1, 1]
+    worker = collections.defaultdict(set)     # (span, launch) -> workers
+    launches = collections.defaultdict(set)   # (span, worker) -> launches
+    for line in _profile_spans(tmp_path):
+        for name, _, _, stats in line:
+            if name in ("serve.assemble", "serve.execute",
+                        "serve.descatter"):
+                worker[name, stats["launch"]].add(stats.get("worker"))
+                launches[name, stats.get("worker")].add(stats["launch"])
+            elif name == "serve.restack":
+                assert stats["worker"] in (0, 1), stats
+    for phase in ("serve.assemble", "serve.execute", "serve.descatter"):
+        assert {w for (n, w) in launches if n == phase} == {0, 1}, phase
+    # one launch, one worker, in every phase
+    assert all(len(ws) == 1 for ws in worker.values()), worker
+    for w in (0, 1):
+        assert (launches["serve.assemble", w]
+                == launches["serve.execute", w]
+                == launches["serve.descatter", w])
 
 
 def _lowered(backend):
