@@ -40,15 +40,19 @@ fake-quant reference (observed: exact).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import threading
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import autotune as autotune_lib
 from . import qat as qat_lib
+from ..kernels.cnn_eq.cnn_eq import table_rows
 from .equalizer import (CNNEqConfig, fold_bn, folded_weights, init_bn_state,
                         layer_strides)
 
@@ -114,6 +118,9 @@ class EqualizerEngine:
             from ..kernels.cnn_eq.cnn_eq import cast_weights_bf16
             self._bweights = cast_weights_bf16(self.weights)
         self._strides = layer_strides(self.cfg)
+        # the engine pool's WeightTable this engine was last given a slot
+        # in (None: never pooled); the table's own slot map is the truth
+        self._table: Optional["WeightTable"] = None
 
     # -- construction ------------------------------------------------------
 
@@ -193,25 +200,28 @@ class EqualizerEngine:
         self.tile_m = best
         return best
 
-    def _make_fn(self, tile_m: int) -> Callable[[jnp.ndarray], jnp.ndarray]:
+    def _make_fn(self, tile_m: int, table=None,
+                 slots=None) -> Callable[[jnp.ndarray], jnp.ndarray]:
+        """The backend's kernel at `tile_m`: every batch row through this
+        engine's weights, or, given a weight table (`table_rows` rows of
+        engines of this group) and an int32 slot vector, row i through
+        entry slots[i] (fused backends only)."""
         if self.backend == "ref":
             from ..kernels.cnn_eq.ref import cnn_eq as ref_fn
             return functools.partial(ref_fn, weights=self.weights,
                                      strides=self._strides)
+        weights = self._layer_weights() if table is None else table
+        kw = dict(tile_m=tile_m, interpret=self.interpret, slots=slots)
         if self.backend == "fused_fp32":
             from ..kernels.cnn_eq.cnn_eq import cnn_eq_fused
-            return lambda x: cnn_eq_fused(x, self.weights, self._strides,
-                                          tile_m=tile_m,
-                                          interpret=self.interpret)
+            return lambda x: cnn_eq_fused(x, weights, self._strides, **kw)
         if self.backend == "fused_bf16":
             from ..kernels.cnn_eq.cnn_eq import cnn_eq_fused_bf16
-            return lambda x: cnn_eq_fused_bf16(x, self._bweights,
-                                               self._strides, tile_m=tile_m,
-                                               interpret=self.interpret)
+            return lambda x: cnn_eq_fused_bf16(x, weights, self._strides,
+                                               **kw)
         from ..kernels.cnn_eq.cnn_eq import cnn_eq_fused_int8
-        return lambda x: cnn_eq_fused_int8(x, self._qweights, self._strides,
-                                           self.formats, tile_m=tile_m,
-                                           interpret=self.interpret)
+        return lambda x: cnn_eq_fused_int8(x, weights, self._strides,
+                                           self.formats, **kw)
 
     def _layer_weights(self):
         """The weight pytree the active backend's kernel consumes."""
@@ -286,7 +296,82 @@ class EqualizerEngine:
         }
 
 
-def stacked_engine_fn(engines) -> Callable[[jnp.ndarray], jnp.ndarray]:
+@functools.partial(jax.jit, static_argnames=("backend",))
+def _write_rows(table, weights, slot, backend):
+    """A new table with entry `slot` set to one engine's rows (no
+    donation: the old table stays valid for launches that bound it)."""
+    rows = table_rows(weights, backend)
+    return jax.tree.map(lambda t, r: t.at[slot].set(r), table, rows)
+
+
+class WeightTable:
+    """Resident per-row weights of engines that share a `tune_key()`, on
+    one device, in the fused kernel's operand layout (`table_rows`).
+
+    capacity: weight sets the table holds (its engine pool's
+              `max_engines`); `insert` raises RuntimeError past it.
+    device:   where the table lives (None: JAX's default device,
+              uncommitted, as engine weights are).
+
+    An engine that enters the table gets a free slot, and its rows are
+    written there in one jitted update; `free` returns the slot. A write
+    makes NEW table arrays and never touches the old ones, so a launch
+    that bound a snapshot (`rows`) keeps the weights it was assembled with
+    whatever is freed or written afterwards. Thread-safe: slots and the
+    current snapshot change together under an internal lock.
+    """
+
+    def __init__(self, capacity: int, device=None):
+        self.capacity = capacity
+        self.device = device
+        self._lock = threading.Lock()
+        self._arrays = None               # per layer (w, b); first insert
+        self._slots: Dict[int, int] = {}  # id(engine) → slot
+        self._free = list(range(capacity - 1, -1, -1))
+
+    def insert(self, engine: "EqualizerEngine") -> bool:
+        """Give `engine` a slot and write its rows there; False (and no
+        write) if it holds one already. The caller keeps the engine alive
+        while it is in."""
+        weights = jax.device_put(engine._layer_weights(), self.device)
+        with self._lock:
+            if id(engine) in self._slots:
+                return False
+            if not self._free:
+                raise RuntimeError(
+                    f"weight table full ({self.capacity} slots)")
+            if self._arrays is None:
+                self._arrays = jax.tree.map(
+                    lambda r: jax.device_put(np.zeros(
+                        (self.capacity,) + r.shape, r.dtype), self.device),
+                    jax.eval_shape(functools.partial(
+                        table_rows, backend=engine.backend), weights))
+            slot = self._free.pop()
+            self._arrays = _write_rows(self._arrays, weights,
+                                       np.int32(slot), engine.backend)
+            self._slots[id(engine)] = slot
+        engine._table = self
+        return True
+
+    def free(self, engine: "EqualizerEngine") -> None:
+        with self._lock:
+            slot = self._slots.pop(id(engine), None)
+            if slot is not None:
+                self._free.append(slot)
+
+    def rows(self, engines) -> Optional[Tuple[Any, np.ndarray]]:
+        """(current table snapshot, int32 slot per engine), or None unless
+        every engine holds a slot here."""
+        with self._lock:
+            try:
+                slots = [self._slots[id(e)] for e in engines]
+            except KeyError:
+                return None
+            return self._arrays, np.asarray(slots, np.int32)
+
+
+def stacked_engine_fn(engines, stacking=None
+                      ) -> Callable[[jnp.ndarray], jnp.ndarray]:
     """Fuse same-group engines into ONE batched launch with per-row weights.
 
     engines: a sequence of `EqualizerEngine`s whose `group_key()`s agree.
@@ -296,8 +381,19 @@ def stacked_engine_fn(engines) -> Callable[[jnp.ndarray], jnp.ndarray]:
     This is the TPU analogue of the paper's DOP-parallel datapath serving
     many links at once: one kernel grid, many tenants.
 
-    The "ref" backend has no batched-weights kernel; it falls back to a
-    per-row loop (kept so every backend can be served and tested).
+    When every engine holds a slot in one `WeightTable` (an engine pool's
+    resident table; one engine too), the launch reads that table's
+    current snapshot at the engines' slots, and no device work happens
+    here. Otherwise one engine runs alone (`e0(x)`), and two or more have
+    their rows stacked once into a private table with slots 0…B−1 (eager
+    device ops, inside `stacking(B)` when that context factory is given —
+    the serving scheduler's `serve.restack` span). Both kinds of table
+    run the same program (the backend's kernel with a slot vector) at
+    equal table sizes.
+
+    The "ref" backend has no batched-weights kernel (nor a table); it
+    falls back to a per-row loop (kept so every backend can be served and
+    tested).
     """
     if not engines:
         raise ValueError("stacked_engine_fn needs at least one engine")
@@ -307,29 +403,21 @@ def stacked_engine_fn(engines) -> Callable[[jnp.ndarray], jnp.ndarray]:
         if e.group_key() != key:
             raise ValueError(
                 f"engines are not batch-compatible: {e.group_key()} != {key}")
-    if len(engines) == 1:
-        return lambda x: e0(x)
-    if e0.backend == "ref":
-        fns = [e._make_fn(e.resolved_tile_m()) for e in engines]
-        return lambda x: jnp.concatenate(
-            [fn(x[i:i + 1]) for i, fn in enumerate(fns)], axis=0)
-
-    per = [e._layer_weights() for e in engines]
-    stacked = tuple(
-        (jnp.stack([p[layer][0] for p in per]),
-         jnp.stack([p[layer][1] for p in per]))
-        for layer in range(len(per[0])))
-    tile_m = e0.resolved_tile_m()
-    strides = e0._strides
-    if e0.backend == "fused_fp32":
-        from ..kernels.cnn_eq.cnn_eq import cnn_eq_fused
-        return lambda x: cnn_eq_fused(x, stacked, strides, tile_m=tile_m,
-                                      interpret=e0.interpret)
-    if e0.backend == "fused_bf16":
-        from ..kernels.cnn_eq.cnn_eq import cnn_eq_fused_bf16
-        return lambda x: cnn_eq_fused_bf16(x, stacked, strides,
-                                           tile_m=tile_m,
-                                           interpret=e0.interpret)
-    from ..kernels.cnn_eq.cnn_eq import cnn_eq_fused_int8
-    return lambda x: cnn_eq_fused_int8(x, stacked, strides, e0.formats,
-                                       tile_m=tile_m, interpret=e0.interpret)
+    table = e0._table
+    held = None
+    if table is not None and all(e._table is table for e in engines):
+        held = table.rows(engines)
+    if held is None:
+        if len(engines) == 1:
+            return lambda x: e0(x)
+        if e0.backend == "ref":
+            fns = [e._make_fn(e.resolved_tile_m()) for e in engines]
+            return lambda x: jnp.concatenate(
+                [fn(x[i:i + 1]) for i, fn in enumerate(fns)], axis=0)
+        with (stacking(len(engines)) if stacking is not None
+              else contextlib.nullcontext()):
+            per = [table_rows(e._layer_weights(), e.backend)
+                   for e in engines]
+            held = (jax.tree.map(lambda *r: jnp.stack(r), *per),
+                    np.arange(len(engines), dtype=np.int32))
+    return e0._make_fn(e0.resolved_tile_m(), *held)

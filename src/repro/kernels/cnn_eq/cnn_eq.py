@@ -59,6 +59,7 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
 from .ref import receptive_halo
@@ -213,8 +214,27 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return bool(interpret)
 
 
+def _operand_layout(w, b):
+    """(…, C_out, C_in, K) weights and (…, C_out) biases → the kernel's
+    operand layout: (…, C_out, K·C_in) weight columns (column k·C_in + c is
+    tap k, input channel c) and (…, C_out, 1) float32 bias columns."""
+    w = jnp.swapaxes(w, -1, -2)                    # (…, C_out, K, C_in)
+    w = w.reshape(w.shape[:-2] + (-1,))            # (…, C_out, K·C_in)
+    return w, b.astype(jnp.float32)[..., None]     # (…, C_out, 1)
+
+
+def _table_kernels(table) -> Tuple[int, ...]:
+    """Kernel sizes of a weight table's layers: layer 0 reads the one input
+    channel, layer i the C_out of layer i-1."""
+    kernels, c_in = [], 1
+    for item in table:
+        kernels.append(int(item[0].shape[-1]) // c_in)
+        c_in = int(item[0].shape[-2])
+    return tuple(kernels)
+
+
 def _fused_call(kernel_body, x, weights, strides, tile_m, interpret, name,
-                **kernel_kwargs):
+                slots=None, **kernel_kwargs):
     """Shared grid/BlockSpec plumbing for all fused kernel bodies.
 
     `name` is the kernel's stable name in compiled programs and device
@@ -222,18 +242,24 @@ def _fused_call(kernel_body, x, weights, strides, tile_m, interpret, name,
     wrapper's own ops carry the named scopes `tile_windows` (the per-tile
     input windows) and `interleave` (channel-major tiles to symbol order).
 
-    Weights are either SHARED — w: (C_out, C_in, K) broadcast to every batch
-    row — or STACKED per row — w: (B, C_out, C_in, K), b: (B, C_out), batch
-    row i computed with weight set i. The stacked form is the multi-tenant
-    serving path: one launch, per-tenant weights selected by the BlockSpec.
+    Weights are either SHARED — w: (C_out, C_in, K), b: (C_out,) broadcast
+    to every batch row, with `slots` None — or a TABLE of per-row weight
+    sets already in the operand layout (`table_rows`) — w: (N, C_out,
+    K·C_in), b: (N, C_out, 1) — with `slots` an int32 (B,) vector: batch
+    row i is computed with table entry slots[i]. The table form is the
+    multi-tenant serving path: one launch, per-tenant weights selected by
+    the BlockSpec index map from the scalar-prefetched slot vector, with
+    no weight op in the launch. Each row is bitwise what the shared form
+    computes with that entry's weights alone: same kernel body, same
+    block shapes; only the weights' index map differs.
 
     Layouts the TPU compiler accepts, all built here by XLA:
       * input: per-tile windows of the padded stream in T = ∏strides
         phases, (B, n_tiles, T, in_cols), so every in-kernel tap is a
         static unit-stride slice and no load needs an aligned offset;
-      * weights: one (C_out, K·C_in) matrix per layer (column k·C_in + c
-        is tap k, input channel c), biases and rescales as (C_out, 1)
-        columns; the batch dim of stacked operands is squeezed;
+      * weights: one (C_out, K·C_in) matrix per layer, biases and
+        rescales as (C_out, 1) columns (`_operand_layout`); the table dim
+        of a table's operands is squeezed;
       * output: channel-major (B, n_tiles, V_p, tile_m) tiles whose last two
         block dims equal the array's, so every tile_m ≥ 1 is a legal block;
         the symbol interleave s = m·V_p + c happens after the kernel.
@@ -242,13 +268,15 @@ def _fused_call(kernel_body, x, weights, strides, tile_m, interpret, name,
     if tile_m < 1:
         raise ValueError(f"tile_m must be a positive int, got {tile_m}")
     batch, width = x.shape
-    stacked = weights[0][0].ndim == 4
-    if stacked and int(weights[0][0].shape[0]) != batch:
-        raise ValueError(
-            f"stacked weights carry {int(weights[0][0].shape[0])} rows but "
-            f"x has batch {batch}")
-    kernels = tuple(int(item[0].shape[-1]) for item in weights)
-    v_parallel = int(weights[-1][0].shape[-3])
+    if slots is None:
+        kernels = tuple(int(item[0].shape[-1]) for item in weights)
+        v_parallel = int(weights[-1][0].shape[-3])
+    else:
+        if tuple(slots.shape) != (batch,):
+            raise ValueError(f"slots has shape {tuple(slots.shape)}, "
+                             f"x has batch {batch}")
+        kernels = _table_kernels(weights)
+        v_parallel = int(weights[-1][0].shape[-2])
     total_stride = int(np.prod(strides))
     n_pos = width // total_stride                  # final-layer positions
     n_syms = n_pos * v_parallel
@@ -279,43 +307,53 @@ def _fused_call(kernel_body, x, weights, strides, tile_m, interpret, name,
             axis=2)[:, :, :in_cols]
         xp = jnp.swapaxes(xp, 2, 3)                # (B, n_tiles, T, in_cols)
 
+    # index maps take (ib, it) and, in a table launch, the slot vector
     def full(shape):
-        return pl.BlockSpec(shape, lambda ib, it: (0,) * len(shape))
+        return pl.BlockSpec(shape, lambda ib, it, *_: (0,) * len(shape))
 
     def per_row(shape):
         return pl.BlockSpec((None,) + shape,
-                            lambda ib, it: (ib,) + (0,) * len(shape))
+                            lambda ib, it, slots: (slots[ib],)
+                            + (0,) * len(shape))
 
     flat: list[jnp.ndarray] = [xp]
     in_specs = [pl.BlockSpec((None, None) + xp.shape[2:],
-                             lambda ib, it: (ib, it, 0, 0))]
+                             lambda ib, it, *_: (ib, it, 0, 0))]
     for item in weights:
-        w = jnp.swapaxes(item[0], -1, -2)          # (…, C_out, K, C_in)
-        w = w.reshape(w.shape[:-2] + (-1,))        # (…, C_out, K·C_in)
-        b = item[1].astype(jnp.float32)[..., None]  # (…, C_out, 1)
+        if slots is None:
+            w, b = _operand_layout(item[0], item[1])
+            spec = full
+        else:
+            w, b = item[0], item[1]
+            spec = per_row
         flat += [w, b]
-        spec = per_row if stacked else full
         in_specs += [spec(w.shape[-2:]), spec(b.shape[-2:])]
         # trailing per-layer operands (e.g. the int8 rescale column) are
-        # SHARED across batch rows even in stacked launches: they derive
+        # SHARED across batch rows even in table launches: they derive
         # from the static formats, which every engine in a group shares
         # (formats are part of group_key)
         for extra in item[2:]:
             flat.append(extra[:, None])
             in_specs.append(full(flat[-1].shape))
 
-    out = pl.pallas_call(
-        functools.partial(kernel_body, tile_m=tile_m, kernels=kernels,
-                          strides=strides, **kernel_kwargs),
-        grid=(batch, n_tiles),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, v_parallel, tile_m),
-                               lambda ib, it: (ib, it, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (batch, n_tiles, v_parallel, tile_m), x.dtype),
-        interpret=interpret,
-        name=name,
-    )(*flat)
+    body = functools.partial(kernel_body, tile_m=tile_m, kernels=kernels,
+                             strides=strides, **kernel_kwargs)
+    out_specs = pl.BlockSpec((None, None, v_parallel, tile_m),
+                             lambda ib, it, *_: (ib, it, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((batch, n_tiles, v_parallel, tile_m),
+                                     x.dtype)
+    if slots is None:
+        out = pl.pallas_call(body, grid=(batch, n_tiles), in_specs=in_specs,
+                             out_specs=out_specs, out_shape=out_shape,
+                             interpret=interpret, name=name)(*flat)
+    else:
+        out = pl.pallas_call(
+            lambda slots_ref, *refs: body(*refs),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(batch, n_tiles),
+                in_specs=in_specs, out_specs=out_specs),
+            out_shape=out_shape, interpret=interpret,
+            name=name)(slots.astype(jnp.int32), *flat)
     # (B, n_tiles, V_p, tile_m) → interleave channels: symbol s = m·V_p + c
     with jax.named_scope("interleave"):
         out = jnp.swapaxes(out, 2, 3).reshape(
@@ -328,16 +366,18 @@ def _fused_call(kernel_body, x, weights, strides, tile_m, interpret, name,
 def cnn_eq_fused(x: jnp.ndarray,
                  weights: Tuple[Tuple[jnp.ndarray, jnp.ndarray], ...],
                  strides: Tuple[int, ...], tile_m: int = 64,
-                 interpret: bool | None = None) -> jnp.ndarray:
+                 interpret: bool | None = None,
+                 slots: jnp.ndarray | None = None) -> jnp.ndarray:
     """Fused fp32 equalizer forward. x: (B, W) → (B, W//N_os) symbols.
 
-    weights: ((w_1, b_1), …, (w_L, b_L)) — BN pre-folded (equalizer.fold_bn).
-    Shared (w: (C_out, C_in, K)) or per-row stacked (w: (B, C_out, C_in, K))
-    — see `_fused_call`. strides: (V_p, 1, …, N_os).
-    Output length = W // (V_p·N_os) · V_p.
+    weights: ((w_1, b_1), …, (w_L, b_L)) — BN pre-folded (equalizer.fold_bn),
+    shared by every batch row (w: (C_out, C_in, K)); or, with `slots` an
+    int32 (B,) vector, a weight table (`table_rows` rows stacked) whose
+    entry slots[i] row i runs through — see `_fused_call`.
+    strides: (V_p, 1, …, N_os). Output length = W // (V_p·N_os) · V_p.
     """
     return _fused_call(_cnn_eq_kernel, x, weights, strides, tile_m, interpret,
-                       "cnn_eq_fused_fp32", operand=jnp.float32)
+                       "cnn_eq_fused_fp32", slots=slots, operand=jnp.float32)
 
 
 def cast_weights_bf16(
@@ -354,20 +394,24 @@ def cast_weights_bf16(
 def cnn_eq_fused_bf16(x: jnp.ndarray,
                       bweights: Tuple[Tuple[jnp.ndarray, jnp.ndarray], ...],
                       strides: Tuple[int, ...], tile_m: int = 64,
-                      interpret: bool | None = None) -> jnp.ndarray:
+                      interpret: bool | None = None,
+                      slots: jnp.ndarray | None = None) -> jnp.ndarray:
     """Fused bf16 equalizer forward: bf16 operands, fp32 accumulation.
 
     The deployment path for QAT formats in the 9–16-bit range
     (`qat.deployment_dtype() == "bfloat16"`). bweights from
     `cast_weights_bf16` (fp32 weights are accepted and rounded to bf16).
     Matches the pure-jnp oracle `ref.cnn_eq_bf16` bitwise: bf16 products
-    are exact in fp32 and both add them in the same order. Shared or
-    per-row stacked weights, like `cnn_eq_fused`.
+    are exact in fp32 and both add them in the same order. Weights shared
+    by every batch row, or a weight table with `slots`, like
+    `cnn_eq_fused`; a table holds the rounded weights already.
     """
-    rounded = tuple((w.astype(jnp.bfloat16).astype(jnp.float32), b)
-                    for w, b in bweights)
-    return _fused_call(_cnn_eq_kernel, x, rounded, strides, tile_m,
-                       interpret, "cnn_eq_fused_bf16", operand=jnp.bfloat16)
+    if slots is None:
+        bweights = tuple((w.astype(jnp.bfloat16).astype(jnp.float32), b)
+                         for w, b in bweights)
+    return _fused_call(_cnn_eq_kernel, x, bweights, strides, tile_m,
+                       interpret, "cnn_eq_fused_bf16", slots=slots,
+                       operand=jnp.bfloat16)
 
 
 def quantize_weights_int8(
@@ -407,31 +451,64 @@ def cnn_eq_fused_int8(x: jnp.ndarray,
                       strides: Tuple[int, ...],
                       formats: Tuple[Tuple[int, int, int, int], ...],
                       tile_m: int = 64,
-                      interpret: bool | None = None) -> jnp.ndarray:
+                      interpret: bool | None = None,
+                      slots: jnp.ndarray | None = None) -> jnp.ndarray:
     """Fused INT8 equalizer forward (see module docstring datapath diagram).
 
-    qweights: ((w_q int8, b fp32), …) from `quantize_weights_int8`.
+    qweights: ((w_q int8, b fp32), …) from `quantize_weights_int8`, shared
+              by every batch row; or, with `slots`, a weight table like
+              `cnn_eq_fused`'s (int32 grid values).
     formats:  per-layer (w_int, w_frac, a_int, a_frac) — static, baked into
               the kernel as requant scales/clip bounds; w_int/w_frac may be
               per-output-channel tuples. Every format must fit a signed
               8-bit grid: the in-kernel requant casts to int8, which would
               silently WRAP (not saturate) wider grids.
     """
+    _check_int8_formats(formats)
+    c_out = -3 if slots is None else -2
+    withscale = tuple((w.astype(jnp.int32), b,
+                       _int8_rescale(int(w.shape[c_out]), fmt))
+                      for (w, b), fmt in zip(qweights, formats))
+    return _fused_call(_cnn_eq_kernel_int8, x, withscale, strides,
+                       tile_m, interpret, "cnn_eq_fused_int8", slots=slots,
+                       formats=formats)
+
+
+def _check_int8_formats(formats) -> None:
+    """Every format must fit a signed 8-bit grid: the in-kernel requant
+    casts to int8, which would silently WRAP (not saturate) wider grids."""
     for i, (wi, wf, ai, af) in enumerate(formats):
         wi_col, wf_col = _wformat_cols(wi, wf)
         if int(np.max(wi_col + wf_col)) + 1 > 8 or ai + af + 1 > 8:
             raise ValueError(
                 f"layer {i} format (Q{wi}.{wf} w / Q{ai}.{af} a) does not "
                 f"fit int8; the int8 requant would wrap silently")
-    # per-layer rescale column: 2^-(w_frac + a_frac), broadcast to (C_out,)
-    # — Pallas kernels cannot capture array constants, so the (possibly
-    # per-channel) scale travels as a third per-layer operand
-    withscale = []
-    for (w, b), (wi, wf, ai, af) in zip(qweights, formats):
-        c_out = int(w.shape[-3])
-        _, wf_col = _wformat_cols(wi, wf)
-        scale = np.broadcast_to(np.exp2(-(wf_col + af)).reshape(-1),
-                                (c_out,)).astype(np.float32)
-        withscale.append((w.astype(jnp.int32), b, jnp.asarray(scale)))
-    return _fused_call(_cnn_eq_kernel_int8, x, tuple(withscale), strides,
-                       tile_m, interpret, "cnn_eq_fused_int8", formats=formats)
+
+
+def _int8_rescale(c_out: int, fmt) -> jnp.ndarray:
+    """A layer's rescale column 2^-(w_frac + a_frac), broadcast to
+    (C_out,) — Pallas kernels cannot capture array constants, so the
+    (possibly per-channel) scale travels as a third per-layer operand."""
+    wi, wf, _, af = fmt
+    _, wf_col = _wformat_cols(wi, wf)
+    return jnp.asarray(np.broadcast_to(np.exp2(-(wf_col + af)).reshape(-1),
+                                       (c_out,)).astype(np.float32))
+
+
+def table_rows(weights, backend: str):
+    """One engine's deployed layer weights → the rows a weight table holds
+    for it: per layer (w (C_out, K·C_in), b (C_out, 1)) in the operand
+    layout, with exactly the values the shared-weights wrapper of `backend`
+    hands the kernel — fp32 as given, bf16 rounded to bf16 and widened to
+    fp32, int8 widened to int32.
+
+    weights: `EqualizerEngine._layer_weights()` — fp32 folded weights,
+    `cast_weights_bf16` or `quantize_weights_int8` output."""
+    rows = []
+    for w, b in weights:
+        if backend == "fused_bf16":
+            w = w.astype(jnp.bfloat16).astype(jnp.float32)
+        elif backend == "fused_int8":
+            w = w.astype(jnp.int32)
+        rows.append(_operand_layout(w, b))
+    return tuple(rows)

@@ -162,7 +162,7 @@ class FleetWorker:
         self.idx = idx
         self.device = device
         self._fleet = fleet
-        self.pool = EnginePool(fleet.max_engines)
+        self.pool = EnginePool(fleet.max_engines, device=device)
         self.pool.fault_plan = fleet.fault_plan
         self.pool.clock = fleet.clock
         # per-worker metrics scope: instruments land under
@@ -187,6 +187,7 @@ class FleetWorker:
 
         self.pool.build_hook = _on_build
         scope.callback("pool", self.pool.stats)
+        scope.callback("table_writes_total", lambda: self.pool.table_writes)
         scope.callback("alive", lambda: self.device_lost is None)
         scope.callback("recovery", self.stats.as_dict)
         scope.callback("health", self.monitor.summary)

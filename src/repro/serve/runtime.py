@@ -188,6 +188,7 @@ def _wire_runtime_obs(rt, obs: Observability) -> None:
 
     pool.build_hook = _on_build
     scope.callback("pool", pool.stats)
+    scope.callback("table_writes_total", lambda: pool.table_writes)
     scope.callback("tenants", lambda: len(rt.sessions))
     scope.callback("sessions", lambda: {
         tid: {"syms_emitted": s.syms_emitted,
@@ -960,9 +961,8 @@ class AsyncServeRuntime:
                 self._poison_locked(dead, build_err or err)
             if not good:
                 return None
-            # re-assembly under the lock (fn cache is not thread-safe);
-            # rebuilt engines have fresh ids → natural stacked-fn cache
-            # miss → the replay binds the NEW engines' weights
+            # re-assembly under the lock: the rebuilt engines hold fresh
+            # table slots, so the replay binds the NEW engines' weights
             if self.batcher.tracer.enabled:
                 t = self.batcher.clock()
                 for r in good:

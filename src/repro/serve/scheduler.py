@@ -20,8 +20,9 @@ A launch is split into three phases so an async front-end can pipeline
 them (see `runtime.AsyncServeRuntime`):
 
   take_ready()  policy check + pop + ASSEMBLE: build the padded stacked
-                input and look up the memoized per-group launch fn — pure
-                host work (numpy, dict lookups);
+                input and bind the launch fn to the engine pool's resident
+                weight table and the rows' slots — pure host work (numpy,
+                dict lookups);
   execute()     the device phase: dispatch the fused kernel and block
                 until the stacked output is ready;
   descatter()   host work again: slice each tenant's rows out, append to
@@ -136,10 +137,12 @@ class Request:
 class LaunchBatch:
     """One assembled stacked launch: everything execute() needs, no more.
 
-    Assembly snapshots the padded input `x` and the memoized launch fn so
-    the device phase touches NO scheduler state — the async launcher thread
-    runs execute() without holding the runtime lock. `launch` is the id
-    that the launch's profiler spans (assemble, execute, descatter) and its
+    Assembly snapshots the padded input `x` and the launch fn (bound to
+    the weight-table snapshot of that moment) so the device phase touches
+    NO scheduler state — the async launcher thread runs execute() without
+    holding the runtime lock, and an engine evicted or rebuilt meanwhile
+    cannot change the launch's weights. `launch` is the id that the
+    launch's profiler spans (assemble, execute, descatter) and its
     requests' chunk spans share.
     """
     key: Tuple                      # the group_key the requests share
@@ -215,18 +218,13 @@ class MicroBatcher:
     """Groups pending requests by engine `group_key()` and launches them as
     stacked fused calls under the max-batch / max-wait policy."""
 
-    # stacked-fn cache bound: steady-state traffic cycles through few
-    # distinct (ordered) tenant sets; 64 covers many groups without
-    # pinning unbounded weight stacks
-    FN_CACHE_MAX = 64
     # default latency-window bound; the live bound comes from
     # `Retention.latency_window` (same default) — a bounded window, not the
     # full history (unbounded streams would otherwise leak one Request,
     # with its symbols array, per chunk forever)
     COMPLETED_MAX = 8192
-    COUNTERS = ("group_fn_hits", "group_fn_misses", "restacks",
-                "launched_samples", "useful_samples", "h2d_bytes",
-                "d2h_bytes")
+    COUNTERS = ("table_launches", "restacks", "launched_samples",
+                "useful_samples", "h2d_bytes", "d2h_bytes")
 
     def __init__(self, policy: Optional[BatchPolicy] = None,
                  clock: Callable[[], float] = time.perf_counter,
@@ -257,9 +255,6 @@ class MicroBatcher:
         scope.callback("latency", self.latency_stats)
         scope.callback("traffic", self.traffic_stats)
         self._groups: Dict[Tuple, List[Request]] = {}
-        # (id(engine), …) → (engine refs, stacked fn). Holding the refs
-        # keeps the ids valid; bounded FIFO so evicted engines can be GC'd.
-        self._fn_cache: "Dict[Tuple, Tuple[list, Callable]]" = {}
         self.completed: Deque[Request] = deque(maxlen=window)
         self.batch_sizes: Deque[int] = deque(maxlen=window)
         # tune_key (group_key minus tile) → live width/occupancy histograms
@@ -267,11 +262,11 @@ class MicroBatcher:
         self.total_requests = 0
         self.launches = 0
         # launch counters (plain ints, registered as serve.<name>_total):
-        # memoized group fn hits/misses, misses that stacked weights,
-        # samples launched (B·W, padding included) and useful (Σ plan
-        # widths) over landed launches, bytes copied each way per attempt
-        self.group_fn_hits = 0
-        self.group_fn_misses = 0
+        # multi-row launches served from a pool's resident weight table,
+        # those that had to stack weights instead, samples launched (B·W,
+        # padding included) and useful (Σ plan widths) over landed
+        # launches, bytes copied each way per attempt
+        self.table_launches = 0
         self.restacks = 0
         self.launched_samples = 0
         self.useful_samples = 0
@@ -422,7 +417,7 @@ class MicroBatcher:
 
     def assemble(self, key: Tuple, reqs: List[Request]) -> LaunchBatch:
         """Host phase 1: pad the requests' plans to one width bucket, stack
-        them into the (B, W) launch input, bind the memoized group fn."""
+        them into the (B, W) launch input, bind the group's launch fn."""
         launch = next(_LAUNCH_IDS)
         with self._annotate("serve.assemble", launch=launch,
                             rows=len(reqs)):
@@ -619,28 +614,21 @@ class MicroBatcher:
         return -(-w // q) * q                      # ceil to bucket quantum
 
     def _group_fn(self, engines) -> Callable:
-        """Memoized stacked launch fn: steady-state round-robin traffic
-        re-batches the SAME engines in the SAME order every round, so the
-        per-launch weight re-stack (and its host→device transfer) is paid
-        once per tenant set, not once per launch. A miss on two or more
-        engines of a fused backend is a restack: the weight stack is built
-        by eager device ops (`serve.restack` span, `restacks` counter)."""
-        key = tuple(id(e) for e in engines)
-        hit = self._fn_cache.get(key)
-        if hit is not None:
-            self.group_fn_hits += 1
-            return hit[1]
-        self.group_fn_misses += 1
-        if len(engines) > 1 and engines[0].backend != "ref":
-            # a miss that stacks the tenants' weights on the device
+        """The stacked launch fn of these engines, in row order. Two or
+        more engines of a fused backend that hold slots in one pool table
+        make a table launch (`table_launches`); otherwise their weights
+        are stacked by eager device ops (`serve.restack` span,
+        `restacks` counter)."""
+        restacks = self.restacks
+
+        def stacking(rows: int):
             self.restacks += 1
-            with self._annotate("serve.restack", rows=len(engines)):
-                fn = stacked_engine_fn(engines)
-        else:
-            fn = stacked_engine_fn(engines)
-        self._fn_cache[key] = (list(engines), fn)
-        while len(self._fn_cache) > self.FN_CACHE_MAX:
-            self._fn_cache.pop(next(iter(self._fn_cache)))
+            return self._annotate("serve.restack", rows=rows)
+
+        fn = stacked_engine_fn(engines, stacking=stacking)
+        if (len(engines) > 1 and engines[0].backend != "ref"
+                and self.restacks == restacks):
+            self.table_launches += 1
         return fn
 
     # -- accounting --------------------------------------------------------

@@ -2,9 +2,11 @@
 micro-batcher's launch counters, and the named scopes and kernel names
 that device profiles attribute op time by.
 
-  * a synchronous `ServeRuntime` sequence with known memoized-fn hits,
-    misses and weight re-stacks, and launched/useful samples and copied
-    bytes derived from the assembled requests;
+  * a synchronous `ServeRuntime` sequence with known table launches,
+    weight-table writes and (none) re-stacks, and launched/useful samples
+    and copied bytes derived from the assembled requests;
+  * a one-engine pool under the profiler: a launch whose engines do not
+    share the pool's table re-stacks inside `serve.assemble`;
   * a short `AsyncServeRuntime` run under `jax.profiler.trace`: every
     documented `serve.*` span is in the profile, nested as documented,
     and one launch id joins assemble, execute, descatter and the chunks'
@@ -76,15 +78,16 @@ def test_sync_runtime_launch_counters():
     rt.batcher.assemble = spy
     rng = np.random.default_rng(0)
     sizes = iter([1500, 2300, 1700, 2000, 1900, 1300, 2600])
-    for tid in "abab":                 # (a, b): miss + restack, then hit
+    for tid in "abab":                 # (a, b) twice: table launches
         rt.submit(tid, _chunk(rng, next(sizes)))
     rt.submit("c", _chunk(rng, next(sizes)))
-    rt.drain()                         # (c): miss, one engine, no restack
-    for tid in "ba":                   # (b, a): another order, a restack
+    rt.drain()                         # (c): one engine, its own weights
+    for tid in "ba":                   # (b, a): another order, same table
         rt.submit(tid, _chunk(rng, next(sizes)))
     b = rt.batcher
     assert [len(w) for w in widths] == [2, 2, 1, 2], widths
-    assert (b.group_fn_hits, b.group_fn_misses, b.restacks) == (1, 3, 2)
+    assert (b.table_launches, b.restacks) == (3, 0)
+    assert rt.pool.table_writes == 3             # one per engine built
     e = rt.sessions.get("a").engine
     quantum = e.resolved_tile_m() * e.total_stride
     launched = sum(len(w) * -(-max(w) // quantum) * quantum for w in widths)
@@ -93,8 +96,9 @@ def test_sync_runtime_launch_counters():
     assert b.h2d_bytes == 4 * launched                 # float32 rows in
     assert b.d2h_bytes == 4 * launched // CFG.n_os     # a symbol per N_os
     snap = rt.obs.snapshot()["serve"]
-    assert snap["restacks_total"] == 2
-    assert snap["group_fn_hits_total"] == 1
+    assert snap["restacks_total"] == 0
+    assert snap["table_launches_total"] == 3
+    assert snap["table_writes_total"] == 3
     assert snap["useful_samples_total"] == b.useful_samples
     assert snap["launches_total"] == 4
 
@@ -164,7 +168,9 @@ def test_profiler_spans_nest_and_share_launch_ids(tmp_path):
             jax.profiler.stop_trace()
     lines = _profile_spans(tmp_path)
     seen = collections.Counter(sp[0] for line in lines for sp in line)
-    assert set(seen) == set(NESTING), seen
+    # every engine holds a slot in the pool's table: no launch re-stacks
+    # (the fallback's span is the next test's)
+    assert set(seen) == set(NESTING) - {"serve.restack"}, seen
     for line in lines:
         for span in line:
             parent = _parent(span, line)
@@ -184,12 +190,37 @@ def test_profiler_spans_nest_and_share_launch_ids(tmp_path):
     for phase in ("serve.execute", "serve.descatter"):
         ids = [st["launch"] for st in by_name[phase]]
         assert sorted(ids) == sorted(rows), phase
-    assert all(st["rows"] >= 2 for st in by_name["serve.restack"])
     chunks = collections.Counter(s.launch for s in obs.tracer.sealed_spans())
     assert dict(chunks) == rows
     exported = [ev for ev in obs.chrome_trace()["traceEvents"]
                 if ev["name"].startswith("chunk ")]
     assert {ev["args"]["launch"] for ev in exported} == set(rows)
+
+
+def test_restack_span_nests_in_assemble_when_engines_share_no_table(
+        tmp_path):
+    """A one-engine pool serving two tenants: fetching the second row's
+    engine at assembly evicts the first's, so the launch stacks their
+    weights itself — one `serve.restack` span (rows 2) inside
+    `serve.assemble`, with the `restacks` counter."""
+    rt = ServeRuntime(BatchPolicy(max_batch=2, max_wait_s=1e9),
+                      clock=FakeClock(), max_engines=1)
+    for i, tid in enumerate("ab"):
+        rt.open(_spec(tid, i))
+    rng = np.random.default_rng(3)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for tid in "ab":
+            rt.submit(tid, _chunk(rng))
+    finally:
+        jax.profiler.stop_trace()
+    assert (rt.batcher.restacks, rt.batcher.table_launches) == (1, 0)
+    spans = [(sp, line) for line in _profile_spans(tmp_path)
+             for sp in line if sp[0] == "serve.restack"]
+    assert len(spans) == 1, spans
+    (span, line), = spans
+    assert span[3]["rows"] == 2
+    assert _parent(span, line)[0] == "serve.assemble"
 
 
 def test_fleet_launch_spans_carry_the_worker_index(tmp_path):

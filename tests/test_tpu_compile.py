@@ -24,6 +24,7 @@ from repro.core.engine import EqualizerEngine
 from repro.kernels.cnn_eq import (cast_weights_bf16, cnn_eq_fused,
                                   cnn_eq_fused_bf16, cnn_eq_fused_int8,
                                   quantize_weights_int8)
+from repro.kernels.cnn_eq.cnn_eq import table_rows
 from repro.parallel import halo
 
 CFG = HT.CNN
@@ -60,8 +61,9 @@ def one_chip(topo):
 
 
 def _weights(key, backend, batch=None):
-    """Folded HT weights in the backend's deployment form; stacked to
-    `batch` per-row sets when batch is given."""
+    """Folded HT weights in the backend's deployment form; when batch is
+    given, a weight table of `batch` sets in the kernel's operand layout
+    (`table_rows`)."""
     keys = [key] if batch is None else list(jax.random.split(key, batch))
     sets = []
     for k in keys:
@@ -74,20 +76,17 @@ def _weights(key, backend, batch=None):
         sets.append(w)
     if batch is None:
         return sets[0]
-    return tuple((jnp.stack([s[i][0] for s in sets]),
-                  jnp.stack([s[i][1] for s in sets]))
-                 for i in range(CFG.layers))
+    rows = [table_rows(s, backend) for s in sets]
+    return jax.tree.map(lambda *r: jnp.stack(r), *rows)
 
 
-def _kernel_fn(backend, weights, tile_m):
+def _kernel_fn(backend, weights, tile_m, slots=None):
+    kw = dict(tile_m=tile_m, interpret=False, slots=slots)
     if backend == "fused_fp32":
-        return lambda x: cnn_eq_fused(x, weights, STRIDES, tile_m=tile_m,
-                                      interpret=False)
+        return lambda x: cnn_eq_fused(x, weights, STRIDES, **kw)
     if backend == "fused_bf16":
-        return lambda x: cnn_eq_fused_bf16(x, weights, STRIDES,
-                                           tile_m=tile_m, interpret=False)
-    return lambda x: cnn_eq_fused_int8(x, weights, STRIDES, INT8_FMT,
-                                       tile_m=tile_m, interpret=False)
+        return lambda x: cnn_eq_fused_bf16(x, weights, STRIDES, **kw)
+    return lambda x: cnn_eq_fused_int8(x, weights, STRIDES, INT8_FMT, **kw)
 
 
 def _compile_has_kernel(fn, arg):
@@ -106,12 +105,15 @@ def test_fused_compiles_at_ht_deployment_shape(backend, one_chip):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_fused_compiles_stacked_serving_shape(backend, one_chip):
-    """Per-row tenant weights (the serving launch), 8 tenants at the
-    tile width serving uses."""
-    w = _weights(jax.random.PRNGKey(1), backend, batch=8)
+    """Per-row tenant weights (the serving launch): 8 rows at the tile
+    width serving uses, each selecting its entry of a 64-set weight table
+    through the scalar-prefetched slot vector."""
+    table = _weights(jax.random.PRNGKey(1), backend, batch=64)
+    slots = np.array([5, 63, 0, 5, 17, 2, 40, 1], np.int32)
     x = jax.ShapeDtypeStruct((8, 1024 * CFG.n_os), jnp.float32,
                              sharding=one_chip)
-    _compile_has_kernel(_kernel_fn(backend, w, tile_m=16), x)
+    _compile_has_kernel(_kernel_fn(backend, table, tile_m=16, slots=slots),
+                        x)
 
 
 def test_halo_apply_batched_compiles_on_four_chips(topo):
